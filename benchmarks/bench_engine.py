@@ -79,6 +79,7 @@ from repro.core.fedstep import make_fed_round
 from repro.core.strategies import make_strategy
 from repro.data.pipeline import stage_client_arrays
 from repro.data.synthetic import SynthTask, make_synthetic_client_arrays
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_client_mesh, make_fed_mesh
 from repro.sharding.rules import model_specs
 from repro.models import softmax_reg
@@ -212,6 +213,13 @@ def _time_engine(engine, rounds: int, chunk: int) -> dict:
                 rounds_per_s=round(rps, 2))
 
 
+def _is_resource_exhausted(e: BaseException) -> bool:
+    """A genuine out-of-memory: Python's own, or XLA's RESOURCE_EXHAUSTED
+    status (device or host allocator).  Every other runtime error —
+    a compile refusal from XLA or Mosaic among them — is not one."""
+    return isinstance(e, MemoryError) or "RESOURCE_EXHAUSTED" in str(e)
+
+
 def bench_nscale(cells_spec, rounds: int, chunk: int) -> dict:
     """Unsharded vs client-sharded engine across client counts N.
 
@@ -251,9 +259,10 @@ def bench_nscale(cells_spec, rounds: int, chunk: int) -> dict:
                 cell[label]["selection_comm_bytes_per_round"] = (
                     engine.selection_comm_bytes_per_round)
                 print(f"{cell[label]['rounds_per_s']:.1f} rounds/s")
-            except (MemoryError, RuntimeError) as e:   # XLA OOM surfaces as
-                cell[label] = dict(status="oom",       # RuntimeError on CPU
-                                   error=str(e)[:200])
+            except (MemoryError, jax.errors.JaxRuntimeError) as e:
+                if not _is_resource_exhausted(e):
+                    raise                  # a compile refusal is not an OOM
+                cell[label] = dict(status="oom", error=str(e)[:200])
                 print("OOM")
             del engine   # release staged arrays before the next cell
         if "rounds_per_s" in cell.get("device", {}) \
@@ -304,6 +313,7 @@ def main(argv=None) -> dict:
                     help="output path (the default overwrites the committed "
                          "CI baseline — pass an explicit path to compare)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     if args.quick:
         host_rounds, dev_rounds, chunk = 80, 240, 40

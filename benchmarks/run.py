@@ -92,6 +92,8 @@ def main() -> None:
     ap.add_argument("--only", default=None, choices=list(BENCHES))
     ap.add_argument("--full", action="store_true")
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     names = [args.only] if args.only else list(BENCHES)
     for name in names:
         print(f"\n===== bench: {name} =====")

@@ -19,10 +19,10 @@ indices, at O(n_local) cost — bitwise-identical to slicing, with no
 against ``jax.random`` for even/odd n and blocks straddling the counter
 midpoint).
 
-Only the default threefry implementation has this layout.  When the
-internals are unavailable — a different PRNG impl, typed keys of another
-flavor, or ``jax_threefry_partitionable`` enabled (which changes the
-counter layout) — every helper falls back to the full-width draw + slice:
+Only the default threefry implementation has this layout.  For any other
+key — a different PRNG impl, typed keys of another flavor, or
+``jax_threefry_partitionable`` enabled (which changes the counter
+layout) — every helper falls back to the full-width draw + slice:
 always correct, just not O(n_local).
 
 Out-of-range lanes (``off + j >= n_total``, the shard-padding tail) are
@@ -40,10 +40,7 @@ import jax.numpy as jnp
 __all__ = ["block_bits", "block_bernoulli", "block_uniform",
            "have_block_prng"]
 
-try:                                     # pinned-version private internals;
-    from jax._src.prng import threefry_2x32 as _threefry_2x32
-except ImportError:                      # pragma: no cover - jax internals
-    _threefry_2x32 = None
+from jax._src.prng import threefry_2x32 as _threefry_2x32
 
 
 def _raw_threefry_key(key):
@@ -61,8 +58,7 @@ def _raw_threefry_key(key):
 
 def have_block_prng(key) -> bool:
     """True when O(n_local) block draws are available for ``key``."""
-    return (_threefry_2x32 is not None
-            and not jax.config.jax_threefry_partitionable
+    return (not jax.config.jax_threefry_partitionable
             and _raw_threefry_key(key) is not None)
 
 

@@ -79,6 +79,18 @@ def _check_select_impl(select_impl: str) -> str:
     return select_impl
 
 
+def select_path(select_impl: str, n_clients: int) -> str:
+    """The top-k cut that ``select_impl`` runs over ``n_clients``:
+    ``"xla"``, or the fused kernel's dispatch — ``"compiled"`` (Mosaic on
+    TPU), ``"interpret"`` or ``"ref"`` (fused jnp reference, off-TPU or
+    beyond ``MAX_KERNEL_N``).  The engines report it as
+    ``final_metrics["select_path"]``."""
+    if _check_select_impl(select_impl) == "pallas":
+        from ..kernels.fed_select import dispatch_mode
+        return dispatch_mode(n_clients)
+    return "xla"
+
+
 def _topk_fn(select_impl: str):
     """The (scores, avail, k) -> mask cut for ``select_impl`` — bit-identical
     outputs either way (tests/test_kernels_select.py)."""

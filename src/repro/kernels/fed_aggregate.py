@@ -4,12 +4,13 @@
 
 This is the server-side reduction of cohort deltas — a bandwidth-bound
 weighted masked sum over the cohort axis.  Tiling: the parameter dimension
-is split into (8*128)-aligned VMEM tiles (grid axis 1); the cohort axis K is
-the innermost grid axis, accumulated in an f32 VMEM scratch so each delta
-tile streams HBM->VMEM exactly once (arithmetic intensity ~= 1 FLOP/byte —
-pure HBM-bandwidth roofline, which is why a fused kernel rather than K
-separate scaled adds is worth it: XLA's unfused form reads the accumulator
-K times).
+is viewed lane-dense as (D/128, 128) and split into (tile/128, 128) VMEM
+tiles (grid axis 0, the cohort dimension squeezed out of each block); the
+cohort axis K is the innermost grid axis, accumulated in an f32 VMEM
+scratch so each delta tile streams HBM->VMEM exactly once (arithmetic
+intensity ~= 1 FLOP/byte — pure HBM-bandwidth roofline, which is why a
+fused kernel rather than K separate scaled adds is worth it: XLA's unfused
+form reads the accumulator K times).
 """
 from __future__ import annotations
 
@@ -21,6 +22,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_TILE = 8 * 1024
+_LANES = 128
 
 
 def _agg_kernel(w_ref, v_ref, o_ref, acc_ref, *, nk: int):
@@ -31,7 +33,7 @@ def _agg_kernel(w_ref, v_ref, o_ref, acc_ref, *, nk: int):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     w_k = w_ref[ki]
-    acc_ref[...] += w_k * v_ref[0].astype(jnp.float32)
+    acc_ref[...] += w_k * v_ref[...].astype(jnp.float32)
 
     @pl.when(ki == nk - 1)
     def _fin():
@@ -75,25 +77,29 @@ def fed_aggregate(deltas: jnp.ndarray, weights: jnp.ndarray, *,
 def _fed_aggregate(deltas: jnp.ndarray, weights: jnp.ndarray, *,
                    tile: int, interpret: bool):
     K, D = deltas.shape
+    if tile % (8 * _LANES):
+        raise ValueError(f"tile must be a multiple of {8 * _LANES}, "
+                         f"got {tile}")
     pad = (-D) % tile
     if pad:
         deltas = jnp.pad(deltas, ((0, 0), (0, pad)))
     Dp = D + pad
     nd = Dp // tile
+    tr = tile // _LANES                  # rows of one (tr, 128) tile
 
     out = pl.pallas_call(
         functools.partial(_agg_kernel, nk=K),
         grid=(nd, K),
         in_specs=[
-            pl.BlockSpec((K,), lambda d, k: (0,), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, tile), lambda d, k: (k, d)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((None, tr, _LANES), lambda d, k: (k, d, 0)),
         ],
-        out_specs=pl.BlockSpec((tile,), lambda d, k: (d,)),
-        out_shape=jax.ShapeDtypeStruct((Dp,), deltas.dtype),
-        scratch_shapes=[pltpu.VMEM((tile,), jnp.float32)],
+        out_specs=pl.BlockSpec((tr, _LANES), lambda d, k: (d, 0)),
+        out_shape=jax.ShapeDtypeStruct((Dp // _LANES, _LANES), deltas.dtype),
+        scratch_shapes=[pltpu.VMEM((tr, _LANES), jnp.float32)],
         interpret=interpret,
-    )(weights.astype(jnp.float32), deltas)
-    return out[:D]
+    )(weights.astype(jnp.float32), deltas.reshape(K, Dp // _LANES, _LANES))
+    return out.reshape(Dp)[:D]
 
 
 def fed_aggregate_tree(deltas_tree, weights: jnp.ndarray, *,

@@ -6,33 +6,47 @@
 
 This is the round's control plane — a chain of (N,)-vector ops XLA leaves
 unfused (argsort + scatter + compare + EMA + renormalize reads the client
-axis ~6×).  The kernel runs the whole pipeline in ONE pass over a single
-VMEM-resident block: sort once (in-VMEM bitonic network), cut at the
-k_eff-th-largest threshold, then compute the EMA and weights from the mask
-while it is still in registers — every (N,) array streams HBM→VMEM exactly
-once.
+axis ~6×).  The kernel holds every (N,) operand as one lane-dense
+``(rows, 128)`` VMEM block, so each streams HBM→VMEM exactly once, and
+runs the cut with nothing but compares and full reductions — the two
+things Mosaic lowers for any block size:
 
-Bit-parity contract: the threshold cut reproduces ``core.selection.
-_topk_mask``'s stable ``(score, id)`` tie-break exactly (see
-``kernels.ref.topk_threshold_mask`` for the reformulation + proof sketch),
-and the EMA/weight arithmetic is op-for-op the unfused ``update_rates`` /
-``core.aggregation`` expressions — masks, r_k, and weights are
-bit-identical to the XLA strategy path (``tests/test_kernels_select.py``,
-``tests/test_parity_matrix.py``).
+1. map each masked score to an order-preserving int32 key
+   (:func:`_order_key`), so float order is integer order;
+2. find the k_eff-th-largest key bit by bit, high bit first: a candidate
+   prefix stays when at least k_eff keys are ``>=`` it (32 counting
+   passes);
+3. find the tie quota's id cutoff the same way: the largest id bound C
+   with at most ``k_eff − |{key > thr}|`` ties below it (⌈log₂ n_pad⌉
+   passes) — the stable ``(score, id)`` tie-break, lowest ids first;
+4. one last pass writes the mask, the rate EMA and the elementwise part of
+   the weight rule while the block is resident.
+
+Each pass walks the block in ``_BLOCK_ROWS``-row slices inside a
+``fori_loop``, so the program's size does not grow with N.
+
+Bit-parity contract: the bisection finds the exact k_eff-th largest value
+and the exact tie cutoff, so the mask is bit-identical to ``core.selection.
+_topk_mask`` (see ``kernels.ref.topk_threshold_mask`` for the threshold
+reformulation); the EMA and the elementwise weight arithmetic are op-for-op
+the unfused ``update_rates`` / ``core.aggregation`` expressions.  The two
+rules that normalise by a sum over the cohort (``uniform``, ``fedavg``)
+take their sum outside the kernel, over the true-length ``(n,)`` vector,
+so it associates exactly as the unfused path's does
+(``tests/test_kernels_select.py``, ``tests/test_parity_matrix.py``).
 
 Backend dispatch (``interpret=None``) differs deliberately from
 ``fed_aggregate``: on TPU the compiled kernel runs; elsewhere we dispatch
 to the *fused jnp reference* (``kernels.ref.fed_select_ref``), NOT the
 Pallas interpreter.  The interpreter is a debugging tool (~100× slow) and
-selection is per-round hot-path — falling back to it would dominate the
-round, while the fused reference is itself faster than the unfused XLA
-chain (``benchmarks/selection_overhead.py``).  ``interpret=True`` forces
-the interpreter explicitly (the parity tests do).
+selection is per-round hot-path.  ``interpret=True`` forces the
+interpreter explicitly (the parity tests do).  :func:`dispatch_mode` names
+the path a call takes; the engines report it as
+``final_metrics["select_path"]``.
 
-The compiled kernel holds the full (N,) block in VMEM: ~6 f32 arrays ≈
-24·N bytes of the ~16 MB/core budget, so N beyond ``MAX_KERNEL_N`` (2^19)
-falls back to the fused reference rather than overflowing VMEM — at that
-scale the pipeline is HBM-bandwidth-bound either way.
+The compiled kernel holds all of its operands in VMEM (~36·N bytes for the
+full select step), so N beyond ``MAX_KERNEL_N`` runs the fused reference;
+docs/kernels.md records why the cap sits where it does.
 """
 from __future__ import annotations
 
@@ -40,24 +54,34 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import ref as _ref
 from .ref import SELECT_WEIGHT_MODES
 
-# Largest client axis the single-block compiled kernel accepts (VMEM cap);
-# beyond it the autodetect path uses the fused jnp reference.
-MAX_KERNEL_N = 1 << 19
+# Largest client axis the single-block compiled kernel accepts: the largest
+# power of two whose select-step operands fit the v5e's VMEM (128 MiB) at
+# _VMEM_LIMIT (docs/kernels.md).  Beyond it the autodetect path runs the
+# fused jnp reference, and dispatch_mode() says so.
+MAX_KERNEL_N = 1 << 21
 
 # Test/debug hook: when set, overrides the ``interpret=None`` autodetect.
 # One of None | "compiled" | "interpret" | "ref".  The parity tests pin
 # "interpret" to drive the engines through the actual Pallas kernel on CPU.
 AUTODETECT_OVERRIDE = None
 
+_LANES = 128
+_BLOCK_ROWS = 256                  # rows per fori_loop step of every pass
+_VMEM_LIMIT = 100 * 1024 * 1024    # scoped-VMEM cap raised for large N
+_INT_MIN = np.int32(-2**31)
 
-def _dispatch(interpret: bool | None, n: int) -> str:
-    """Resolve the execution mode per call (never at import), mirroring
+
+def dispatch_mode(n: int, interpret: bool | None = None) -> str:
+    """The path a call over ``n`` clients takes: ``"compiled"`` (Mosaic
+    kernel), ``"interpret"`` (Pallas interpreter) or ``"ref"`` (fused jnp
+    reference).  Resolved per call (never at import), mirroring
     ``fed_aggregate._default_interpret`` so ``JAX_PLATFORMS`` is honored."""
     if interpret is True:
         return "interpret"
@@ -70,97 +94,158 @@ def _dispatch(interpret: bool | None, n: int) -> str:
     return "ref"
 
 
-def _pow2(n: int) -> int:
-    p = 1
-    while p < n:
-        p *= 2
-    return p
+def _layout(n: int) -> tuple[int, int]:
+    """``(rows, block_rows)`` of the lane-dense block holding ``n`` values:
+    rows a multiple of block_rows, block_rows a multiple of 8 (the f32/int32
+    sublane tile)."""
+    rows = -(-max(n, 1) // _LANES)
+    rows = -(-rows // 8) * 8
+    br = min(_BLOCK_ROWS, rows)
+    return -(-rows // br) * br, br
 
 
-def _bitonic_sort(x: jnp.ndarray) -> jnp.ndarray:
-    """Exact ascending bitonic sort of a power-of-two-length f32 vector.
+def _to_block(x, rows: int):
+    """(n,) → zero-padded (rows, 128)."""
+    return jnp.pad(x, (0, rows * _LANES - x.shape[0])).reshape(rows, _LANES)
 
-    Pure compare-exchange network spelled with reshapes — partner pairs
-    (i, i^j) are rows ``[:, 0, :]``/``[:, 1, :]`` of ``x.reshape(-1, 2, j)``
-    — so it needs no gathers and no 1-D iota, both of which Mosaic rejects
-    inside TPU kernels (2-D ``broadcasted_iota`` supplies the block index).
-    log²(n)/2 elementwise stages; an exact permutation, so the threshold
-    read off it is bit-identical to ``jnp.sort``'s.
-    """
-    n = x.shape[0]
-    k = 2
-    while k <= n:
-        j = k // 2
-        while j >= 1:
-            nb = n // (2 * j)
-            xb = x.reshape(nb, 2, j)
-            lo, hi = xb[:, 0, :], xb[:, 1, :]
-            blk = jax.lax.broadcasted_iota(jnp.int32, (nb, 1), 0)
-            up = ((blk * (2 * j)) & k) == 0          # ascending sub-block?
-            mn, mx = jnp.minimum(lo, hi), jnp.maximum(lo, hi)
-            x = jnp.stack([jnp.where(up, mn, mx),
-                           jnp.where(up, mx, mn)], axis=1).reshape(n)
-            j //= 2
-        k *= 2
-    return x
+
+def _order_key(x):
+    """f32 → int32 with ``x < y ⇔ key(x) < key(y)`` and ``key(x) == key(y)
+    ⇔ x == y`` (−0.0 is folded onto +0.0 first, as float ``==`` does)."""
+    x = jnp.where(x == 0.0, 0.0, x)
+    i = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return jnp.where(i < 0, i ^ np.int32(0x7FFFFFFF), i)
 
 
 # ---------------------------------------------------------------------------
-# Kernel bodies — same traced math as kernels.ref, different memory story:
-# scalars prefetched to SMEM, every (N,) operand a single VMEM block.
+# Kernel bodies.  Refs are (rows, 128); k arrives in SMEM; key_ref is a VMEM
+# scratch holding the order keys between passes.
 # ---------------------------------------------------------------------------
 
-def _mask_kernel(k_ref, scores_ref, avail_ref, mask_ref):
-    avail = avail_ref[...] != 0
-    mask_ref[...] = _ref.topk_threshold_mask(
-        scores_ref[...], avail, k_ref[0], sort_fn=_bitonic_sort)
+def _cut(k_ref, scores_ref, avail_ref, key_ref, emit, *, rows: int,
+         br: int):
+    """Exact top-k_eff cut; calls ``emit(rows_slice, mask_block)`` once per
+    row slice with the final (br, 128) bool mask."""
+    nb = rows // br
+    row = jax.lax.broadcasted_iota(jnp.int32, (br, _LANES), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (br, _LANES), 1)
+    zeros = jnp.zeros((br, _LANES), jnp.int32)
+
+    def rows_of(i):
+        return pl.ds(pl.multiple_of(i * br, br), br)
+
+    def count(pred):
+        """|{elements where pred(i, row_slice) holds}| as an int32 scalar."""
+        def body(i, acc):
+            return acc + pred(i, rows_of(i)).astype(jnp.int32)
+        return jnp.sum(jax.lax.fori_loop(0, nb, body, zeros))
+
+    def keys_pass(i, acc):
+        s = rows_of(i)
+        av = avail_ref[s, :] != 0
+        key_ref[s, :] = _order_key(
+            jnp.where(av, scores_ref[s, :], _ref.SELECT_NEG))
+        return acc + av.astype(jnp.int32)
+
+    n_avail = jnp.sum(jax.lax.fori_loop(0, nb, keys_pass, zeros))
+    k_eff = jnp.minimum(k_ref[0], n_avail)
+
+    # k_eff-th largest key, built high bit first in the unsigned image
+    # (u = key ^ INT_MIN preserves order); k_eff == 0 ends at the maximum,
+    # above which nothing is selected.
+    def key_bit(b, t):
+        cand = t | jnp.left_shift(jnp.int32(1), 31 - b)
+        thr = cand ^ _INT_MIN
+        c = count(lambda i, s: key_ref[s, :] >= thr)
+        return jnp.where(c >= k_eff, cand, t)
+
+    thr = jax.lax.fori_loop(0, 32, key_bit, jnp.int32(0)) ^ _INT_MIN
+    quota = k_eff - count(lambda i, s: key_ref[s, :] > thr)
+
+    def ids(i):
+        return (i * br + row) * _LANES + lane
+
+    def tie(s):
+        return (key_ref[s, :] == thr) & (avail_ref[s, :] != 0)
+
+    # largest id bound with at most `quota` ties below it: the first
+    # `quota` ties in ascending id order (the stable tie-break)
+    id_bits = (rows * _LANES).bit_length()
+
+    def id_bit(b, c0):
+        cand = c0 | jnp.left_shift(jnp.int32(1), id_bits - 1 - b)
+        c = count(lambda i, s: tie(s) & (ids(i) < cand))
+        return jnp.where(c <= quota, cand, c0)
+
+    cut = jax.lax.fori_loop(0, id_bits, id_bit, jnp.int32(0))
+
+    def emit_pass(i, carry):
+        s = rows_of(i)
+        mask = ((key_ref[s, :] > thr) | (tie(s) & (ids(i) < cut))) \
+            & (avail_ref[s, :] != 0)
+        emit(s, mask)
+        return carry
+
+    jax.lax.fori_loop(0, nb, emit_pass, 0)
+
+
+def _mask_kernel(k_ref, scores_ref, avail_ref, mask_ref, key_ref, *,
+                 rows: int, br: int):
+    def emit(s, mask):
+        mask_ref[s, :] = mask.astype(jnp.int32)
+
+    _cut(k_ref, scores_ref, avail_ref, key_ref, emit, rows=rows, br=br)
 
 
 def _select_kernel(k_ref, scores_ref, avail_ref, r_ref, p_ref, rw_ref,
-                   mask_ref, newr_ref, w_ref, *, beta: float,
-                   weight_mode: str, n: int):
-    avail = avail_ref[...] != 0
-    mask = _ref.topk_threshold_mask(
-        scores_ref[...], avail, k_ref[0], sort_fn=_bitonic_sort)
-    # β is a *static* Python float so (1.0 − β) folds to the identical f32
-    # constant the unfused update_rates path uses — a traced SMEM β would
-    # compute 1−β in f32 and could differ by 1 ulp, breaking bit-parity.
-    new_r = (1.0 - beta) * r_ref[...] + beta * mask.astype(jnp.float32)
-    mask_ref[...] = mask
-    newr_ref[...] = new_r
-    # Weight rules reduce over the client axis (1/|S|, Σ p_k): run them on
-    # the static [:n] slice so the reduction has the *real* length — summing
-    # the zero-padded (n_pad,) block would associate differently and drift
-    # the denominator by an ulp, breaking bit-parity with the unfused path.
-    w = _ref.select_weights_ref(mask[:n], new_r[:n], p_ref[:n], rw_ref[:n],
-                                weight_mode)
-    w_ref[...] = jnp.pad(w, (0, mask.shape[0] - n))
+                   mask_ref, newr_ref, w_ref, key_ref, *, beta: float,
+                   weight_mode: str, rows: int, br: int):
+    def emit(s, mask):
+        # β is a *static* Python float so (1.0 − β) folds to the identical
+        # f32 constant the unfused update_rates path uses — a traced SMEM β
+        # would compute 1−β in f32 and could differ by 1 ulp.
+        new_r = (1.0 - beta) * r_ref[s, :] + beta * mask.astype(jnp.float32)
+        mask_ref[s, :] = mask.astype(jnp.int32)
+        newr_ref[s, :] = new_r
+        w_ref[s, :] = _ref.raw_select_weights(
+            mask, new_r, p_ref[s, :], rw_ref[s, :], weight_mode)
 
-
-def _pad_to(x, n_pad: int):
-    return jnp.pad(x, (0, n_pad - x.shape[0]))
-
-
-def _vec_spec(n_pad: int):
-    return pl.BlockSpec((n_pad,), lambda: (0,))
+    _cut(k_ref, scores_ref, avail_ref, key_ref, emit, rows=rows, br=br)
 
 
 _SMEM_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
+def _call(kernel, n: int, n_in: int, n_out: int, *, interpret: bool):
+    """pallas_call over whole (rows, 128) blocks: k in SMEM, ``n_in`` vector
+    operands, ``n_out`` vector results (int32 mask first, then f32)."""
+    rows, br = _layout(n)
+    vec = pl.BlockSpec((rows, _LANES), lambda: (0, 0))
+    out_shape = [jax.ShapeDtypeStruct((rows, _LANES), jnp.int32)]
+    out_shape += [jax.ShapeDtypeStruct((rows, _LANES), jnp.float32)] \
+        * (n_out - 1)
+    return pl.pallas_call(
+        functools.partial(kernel, rows=rows, br=br),
+        in_specs=[_SMEM_SPEC] + [vec] * n_in,
+        out_specs=tuple([vec] * n_out),
+        out_shape=tuple(out_shape),
+        scratch_shapes=[pltpu.VMEM((rows, _LANES), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+    ), rows
+
+
+def _unblock(x, n: int):
+    return x.reshape(-1)[:n]
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _mask_pallas(scores, avail, k, *, interpret: bool):
     n = scores.shape[0]
-    n_pad = _pow2(n)
-    out = pl.pallas_call(
-        _mask_kernel,
-        in_specs=[_SMEM_SPEC, _vec_spec(n_pad), _vec_spec(n_pad)],
-        out_specs=_vec_spec(n_pad),
-        out_shape=jax.ShapeDtypeStruct((n_pad,), jnp.bool_),
-        interpret=interpret,
-    )(k.reshape(1), _pad_to(scores, n_pad),
-      _pad_to(avail.astype(jnp.int32), n_pad))
-    return out[:n]
+    call, rows = _call(_mask_kernel, n, 2, 1, interpret=interpret)
+    (mask,) = call(k.reshape(1), _to_block(scores, rows),
+                   _to_block(avail.astype(jnp.int32), rows))
+    return _unblock(mask, n) != 0
 
 
 @functools.partial(jax.jit,
@@ -168,21 +253,17 @@ def _mask_pallas(scores, avail, k, *, interpret: bool):
 def _select_pallas(scores, avail, k, r, p, rw, *, beta: float,
                    weight_mode: str, interpret: bool):
     n = scores.shape[0]
-    n_pad = _pow2(n)
-    vec = _vec_spec(n_pad)
-    mask, new_r, w = pl.pallas_call(
-        functools.partial(_select_kernel, beta=beta,
-                          weight_mode=weight_mode, n=n),
-        in_specs=[_SMEM_SPEC, vec, vec, vec, vec, vec],
-        out_specs=(vec, vec, vec),
-        out_shape=(jax.ShapeDtypeStruct((n_pad,), jnp.bool_),
-                   jax.ShapeDtypeStruct((n_pad,), jnp.float32),
-                   jax.ShapeDtypeStruct((n_pad,), jnp.float32)),
-        interpret=interpret,
-    )(k.reshape(1), _pad_to(scores, n_pad),
-      _pad_to(avail.astype(jnp.int32), n_pad), _pad_to(r, n_pad),
-      _pad_to(p, n_pad), _pad_to(rw, n_pad))
-    return mask[:n], new_r[:n], w[:n]
+    call, rows = _call(functools.partial(_select_kernel, beta=beta,
+                                         weight_mode=weight_mode),
+                       n, 5, 3, interpret=interpret)
+    mask, new_r, w = call(
+        k.reshape(1), _to_block(scores, rows),
+        _to_block(avail.astype(jnp.int32), rows), _to_block(r, rows),
+        _to_block(p, rows), _to_block(rw, rows))
+    # the sum-normalised rules reduce over the true-length (n,) vector so
+    # the denominator associates exactly as the unfused path's
+    return (_unblock(mask, n) != 0, _unblock(new_r, n),
+            _ref.normalize_select_weights(_unblock(w, n), weight_mode))
 
 
 # jitted fused-jnp fallbacks (the off-TPU production path)
@@ -208,11 +289,12 @@ def fed_select_mask(scores: jnp.ndarray, avail: jnp.ndarray,
     selection cut from ``finalize`` — the EMA/weights then run on the
     *completed* mask and cannot be fused with the cut.
 
-    ``interpret=None`` autodetects: compiled Pallas on TPU, fused jnp
-    reference elsewhere; ``interpret=True`` forces the Pallas interpreter.
+    ``interpret=None`` autodetects (:func:`dispatch_mode`): compiled Pallas
+    on TPU, fused jnp reference elsewhere; ``interpret=True`` forces the
+    Pallas interpreter.
     """
     k = jnp.asarray(k, jnp.int32)
-    mode = _dispatch(interpret, scores.shape[0])
+    mode = dispatch_mode(scores.shape[0], interpret)
     if mode == "ref":
         return _mask_ref_jit(scores, avail, k)
     return _mask_pallas(scores, avail, k, interpret=(mode == "interpret"))
@@ -244,7 +326,7 @@ def fed_select(scores: jnp.ndarray, avail: jnp.ndarray, k: jnp.ndarray,
     beta = float(beta)
     k = jnp.asarray(k, jnp.int32)
     rw = p if r_weight is None else jnp.asarray(r_weight, jnp.float32)
-    mode = _dispatch(interpret, scores.shape[0])
+    mode = dispatch_mode(scores.shape[0], interpret)
     if mode == "ref":
         return _select_ref_jit(scores, avail, k, r, p, rw, beta=beta,
                                weight_mode=weight_mode)

@@ -46,7 +46,7 @@ SELECT_NEG = -1e30
 SELECT_WEIGHT_MODES = ("unbiased", "unbiased_frozen", "uniform", "fedavg")
 
 
-def topk_threshold_mask(scores, avail, k, *, sort_fn=jnp.sort):
+def topk_threshold_mask(scores, avail, k):
     """``core.selection._topk_mask`` reformulated as a threshold cut.
 
     ``_topk_mask`` ranks via a stable ``argsort(-masked)`` and keeps ranks
@@ -57,35 +57,54 @@ def topk_threshold_mask(scores, avail, k, *, sort_fn=jnp.sort):
                                  ties (masked_i == thr) in ascending id order
 
     which needs only a *value* sort (no argsort + scatter) plus a cumsum —
-    cheaper, fusable, and kernel-friendly.  The tie prefix in ascending id
-    order is exactly the stable-sort ``(score, id)`` tie-break, so the
-    returned mask is bit-identical to ``_topk_mask`` (asserted in
-    ``tests/test_kernels_select.py``).
-
-    ``sort_fn`` must be an exact ascending sort of a (N,) f32 vector; the
-    Pallas kernel body swaps in its in-VMEM bitonic network, the reference
-    uses ``jnp.sort`` — both are exact permutations, so the threshold (and
-    hence the mask) cannot differ between the two.
+    cheaper and fusable.  The tie prefix in ascending id order is exactly
+    the stable-sort ``(score, id)`` tie-break, so the returned mask is
+    bit-identical to ``_topk_mask`` (asserted in
+    ``tests/test_kernels_select.py``).  The Pallas kernel finds the same
+    ``thr`` and tie prefix by bisection instead of a sort and a cumsum
+    (``kernels/fed_select.py``).
     """
     n = scores.shape[0]
     avail = avail.astype(bool)
     masked = jnp.where(avail, scores, SELECT_NEG).astype(jnp.float32)
     n_avail = jnp.sum(avail.astype(jnp.int32))
     k_eff = jnp.minimum(k.astype(jnp.int32), n_avail)
-    svals = sort_fn(masked)                      # ascending, exact
     # k_eff-th largest lives at ascending index n - k_eff; k_eff == 0 clips
     # to the maximum, for which the gt/tie counts below select nothing.
-    idx = jnp.clip(n - k_eff, 0, n - 1)
-    # 2-D iota + reshape: Mosaic rejects 1-D iota inside TPU kernel bodies,
-    # and this helper is traced from the Pallas kernels (docs/kernels.md).
-    pos = jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0).reshape(n)
-    thr = jnp.sum(jnp.where(pos == idx, svals, 0.0))
+    thr = jnp.sort(masked)[jnp.clip(n - k_eff, 0, n - 1)]
     gt = masked > thr
     g = jnp.sum(gt.astype(jnp.int32))
     eq = (masked == thr) & avail
     eq_i = eq.astype(jnp.int32)
     tie_rank = jnp.cumsum(eq_i) - eq_i           # exclusive: id-order prefix
     return (gt | (eq & (tie_rank < (k_eff - g)))) & avail
+
+
+def raw_select_weights(mask, new_r, p, r_weight, weight_mode: str):
+    """The elementwise half of :func:`select_weights_ref` (the kernel
+    computes it in its last pass; :func:`normalize_select_weights` adds the
+    cohort-sum half)."""
+    from ..core.hfun import R_MIN
+    if weight_mode == "unbiased":
+        return jnp.where(mask, p / jnp.maximum(new_r, R_MIN), 0.0)
+    if weight_mode == "unbiased_frozen":
+        return jnp.where(mask, p / jnp.maximum(r_weight, R_MIN), 0.0)
+    if weight_mode == "uniform":
+        return mask.astype(jnp.float32)
+    if weight_mode == "fedavg":
+        return jnp.where(mask, p, 0.0)
+    raise ValueError(f"unknown weight_mode {weight_mode!r}; "
+                     f"known: {SELECT_WEIGHT_MODES}")
+
+
+def normalize_select_weights(w, weight_mode: str):
+    """Divide the sum-normalised rules by their cohort sum over the
+    true-length (N,) vector — the same association as ``core.aggregation``."""
+    if weight_mode == "uniform":
+        return w / jnp.maximum(w.sum(), 1.0)
+    if weight_mode == "fedavg":
+        return w / jnp.maximum(w.sum(), 1e-12)
+    return w
 
 
 def select_weights_ref(mask, new_r, p, r_weight, weight_mode: str):
@@ -101,19 +120,9 @@ def select_weights_ref(mask, new_r, p, r_weight, weight_mode: str):
     * ``uniform``         1/|S| over the cohort (fedavg, uniform)
     * ``fedavg``          p_k / Σ_{S} p_k  (fedavg_weighted)
     """
-    from ..core.hfun import R_MIN
-    if weight_mode == "unbiased":
-        return jnp.where(mask, p / jnp.maximum(new_r, R_MIN), 0.0)
-    if weight_mode == "unbiased_frozen":
-        return jnp.where(mask, p / jnp.maximum(r_weight, R_MIN), 0.0)
-    if weight_mode == "uniform":
-        v = mask.astype(jnp.float32)
-        return v / jnp.maximum(v.sum(), 1.0)
-    if weight_mode == "fedavg":
-        w = jnp.where(mask, p, 0.0)
-        return w / jnp.maximum(w.sum(), 1e-12)
-    raise ValueError(f"unknown weight_mode {weight_mode!r}; "
-                     f"known: {SELECT_WEIGHT_MODES}")
+    return normalize_select_weights(
+        raw_select_weights(mask, new_r, p, r_weight, weight_mode),
+        weight_mode)
 
 
 def fed_select_ref(scores, avail, k, r, p, beta, *,

@@ -26,11 +26,8 @@ def _validate_axis_names(axis_names) -> tuple:
 
 
 def _grid_mesh(shape, axis_names) -> Mesh:
-    """Mesh over the first prod(shape) visible devices.
-
-    Built with the ``jax.sharding.Mesh`` constructor directly (not
-    ``jax.make_mesh``, which the oldest CI-matrix jax lacks).
-    """
+    """Mesh over the first prod(shape) visible devices, in
+    ``jax.devices()`` order."""
     names = _validate_axis_names(axis_names)
     if len(names) != len(shape):
         raise ValueError(f"mesh shape {shape} has {len(shape)} dims but "

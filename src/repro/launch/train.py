@@ -42,6 +42,7 @@ from ..sim.completion import COMPLETION_REGISTRY
 from ..sim.runner import TrainResult, run_scenario
 from ..sim.scenario import Scenario, list_scenarios
 from ..sim.spec import RunSpec
+from .compile_cache import use_compile_cache
 
 __all__ = ["TrainResult", "run_federated", "run_arch_smoke", "main"]
 
@@ -194,6 +195,7 @@ def main():
                     help="write the assembled RunSpec JSON before running "
                          "(reproduce later with --spec)")
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.arch:
         run_arch_smoke(args.arch, rounds=args.rounds or 3, seed=args.seed)

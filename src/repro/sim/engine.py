@@ -52,7 +52,7 @@ from ..core.bitmask import pack_bits, unpack_bits_np
 from ..core.fedstep import make_fed_round
 from ..core.selection import cohort_ids_from_mask
 from ..core.strategies import (SelectCtx, get_strategy_entry, make_strategy,
-                               resolve_strategy, strategy_rates)
+                               resolve_strategy, select_path, strategy_rates)
 from ..data import CohortSampler
 from ..data.pipeline import staged_cohort_batch, synth_cohort_batch
 from ..data.synthetic import SynthTask
@@ -495,6 +495,7 @@ def run_scenario_device(scenario: Union[str, Scenario],
     t_end = time.time()
     final = dict(history[-1])
     final["engine"] = engine_label
+    final["select_path"] = select_path(select_impl, n_real)
     final["wall_s"] = t_end - t_start
     # scale accounting (ISSUE 8): resident staged-data bytes (0 when
     # cohorts are synthesized on demand) and per-round selection traffic.

@@ -63,7 +63,7 @@ from ..checkpoint import save_checkpoint
 from ..core.fedstep import make_fed_round
 from ..core.selection import cohort_ids_from_mask
 from ..core.strategies import (SelectCtx, get_strategy_entry, make_strategy,
-                               resolve_strategy, strategy_rates)
+                               resolve_strategy, select_path, strategy_rates)
 from ..data import CohortSampler
 from ..data.pipeline import staged_cohort_batch
 from ..optim import make_optimizer
@@ -452,11 +452,14 @@ def run_scenario_buffered(scenario: Union[str, Scenario],
     rounds = rounds or ctx["rounds_default"]
     algo_label = algo_label or algo_name
     run = _run_buffered_device if engine == "device" else _run_buffered_host
-    return run(ctx, rounds=rounds, seed=seed, eval_every=eval_every,
-               chunk_size=chunk_size, ckpt_dir=ckpt_dir,
-               metrics_path=metrics_path, staleness_power=staleness_power,
-               staleness_discount=staleness_discount,
-               algo_label=algo_label, log_fn=log_fn)
+    result = run(ctx, rounds=rounds, seed=seed, eval_every=eval_every,
+                 chunk_size=chunk_size, ckpt_dir=ckpt_dir,
+                 metrics_path=metrics_path, staleness_power=staleness_power,
+                 staleness_discount=staleness_discount,
+                 algo_label=algo_label, log_fn=log_fn)
+    result.final_metrics["select_path"] = select_path(select_impl,
+                                                      ctx["n_clients"])
+    return result
 
 
 def _open_metrics(metrics_path):

@@ -69,7 +69,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..core.bitmask import pack_bits
@@ -387,9 +386,9 @@ class ShardedEngine:
         else:
             staged_specs = jax.tree.map(lambda _: P(axis), staged.arrays)
             in_specs = (carry_specs, P(), P(), staged_specs, P())
-        self._chunk = jax.jit(shard_map(
+        self._chunk = jax.jit(jax.shard_map(
             chunk_body, mesh=mesh, in_specs=in_specs,
-            out_specs=(carry_specs, stream_specs), check_rep=False))
+            out_specs=(carry_specs, stream_specs), check_vma=False))
 
         def _make_init(r0):
             def init_carry(key):

@@ -57,7 +57,7 @@ from ..checkpoint import save_checkpoint
 from ..configs import PAPER_TASKS
 from ..core.fedstep import make_fed_round
 from ..core.strategies import (SelectCtx, get_strategy_entry, make_strategy,
-                               strategy_rates)
+                               select_path, strategy_rates)
 from ..data import CohortSampler, FederatedData
 from ..data.synthetic import (make_char_lm_federated, make_synthetic_federated,
                               make_vision_federated)
@@ -406,6 +406,7 @@ def run_spec(spec: RunSpec, *, log_fn: Callable = print) -> TrainResult:
     t_end = time.time()
     final = dict(history[-1]) if history else {}
     final["engine"] = "host"
+    final["select_path"] = select_path(rs.select_impl, N)
     if fallback_reason is not None:
         final["engine_fallback"] = fallback_reason
     final["wall_s"] = t_end - t_start

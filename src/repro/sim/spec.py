@@ -94,8 +94,11 @@ class RunSpec:
     select_impl: str = "xla"                    # top-k cut: "xla" | "pallas"
     #   "pallas" routes every topk_strategy through the fused selection
     #   kernel (repro.kernels.fed_select) — bit-identical masks/rates,
-    #   one pass over the client axis.  Unsupported with mesh= (the
-    #   sharded engine keeps its distributed sharded_topk_mask).
+    #   one pass over the client axis; compiled on TPU up to
+    #   MAX_KERNEL_N, the fused jnp reference otherwise, and
+    #   final_metrics["select_path"] reports which ran.  Unsupported
+    #   with mesh= (the sharded engine keeps its distributed
+    #   sharded_topk_mask).
     topk_impl: str = "stream"                   # sharded top-k reduction:
     #   "stream" (ppermute candidate merge, O(k·log D) traffic) |
     #   "allgather" (legacy full candidate gather).  Bit-identical masks

@@ -24,6 +24,7 @@ import json
 import os
 from typing import Callable, Optional, Sequence
 
+from ..launch.compile_cache import use_compile_cache
 from .completion import COMPLETION_REGISTRY
 from .runner import run_scenario
 from .scenario import SCENARIO_REGISTRY, get_scenario, list_scenarios
@@ -193,6 +194,7 @@ def main(argv=None) -> None:
                     if args.aggregations else None)
     mesh_shape = (_parse_mesh_shape(args.mesh_shape)
                   if args.mesh_shape is not None else _UNSET)
+    use_compile_cache()
     run_sweep(scenarios, algorithms, completions=completions,
               aggregations=aggregations,
               rounds=args.rounds, out_dir=args.out,

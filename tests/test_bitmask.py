@@ -11,7 +11,6 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.bitmask import (all_gather_bits, n_words, pack_bits,
@@ -79,10 +78,10 @@ def test_all_gather_bits_matches_bool_gather(n_local):
     mask = np.zeros(n_local * shards, bool)
     mask[:n] = rng.random(n) < 0.5
 
-    f = jax.jit(shard_map(
+    f = jax.jit(jax.shard_map(
         lambda m: all_gather_bits(m, "clients", n),
         mesh=mesh, in_specs=P("clients"), out_specs=P(),
-        check_rep=False))
+        check_vma=False))
     got = np.asarray(f(jnp.asarray(mask)))
     assert got.shape == (n,)
     np.testing.assert_array_equal(got, mask[:n])

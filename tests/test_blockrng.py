@@ -15,7 +15,6 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import blockrng
@@ -66,12 +65,14 @@ def test_block_tail_lanes_defined_and_in_range_exact():
 
 
 def test_fallback_path_matches(monkeypatch):
-    # no threefry internals -> full draw + slice; same in-range values
+    # no block PRNG for the key -> full draw + slice; same in-range values
+    # as the O(n_local) path (which needs the non-partitionable layout)
     key = jax.random.PRNGKey(9)
-    want = np.asarray(block_uniform(key, 200, 50, 60))
-    monkeypatch.setattr(blockrng, "_threefry_2x32", None)
-    assert not blockrng.have_block_prng(key)
-    got = np.asarray(block_uniform(key, 200, 50, 60))
+    with jax.threefry_partitionable(False):
+        assert blockrng.have_block_prng(key)
+        want = np.asarray(block_uniform(key, 200, 50, 60))
+        monkeypatch.setattr(blockrng, "have_block_prng", lambda key: False)
+        got = np.asarray(block_uniform(key, 200, 50, 60))
     np.testing.assert_array_equal(got, want)
 
 
@@ -94,9 +95,9 @@ def test_force_nonempty_block_matches_full(all_down):
         cand = jnp.where(q_blk >= q.max(), tie, -1.0)
         return force_nonempty_block(mask_blk, cand, off, "clients")
 
-    got = jax.jit(shard_map(
+    got = jax.jit(jax.shard_map(
         blk_fn, mesh=mesh, in_specs=(P("clients"), P("clients")),
-        out_specs=P("clients"), check_rep=False))(mask, q)
+        out_specs=P("clients"), check_vma=False))(mask, q)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
@@ -118,9 +119,9 @@ def test_bernoulli_step_block_matches_step(sigma):
                                        axis="clients")
         return mask_blk
 
-    got = np.asarray(jax.jit(shard_map(
+    got = np.asarray(jax.jit(jax.shard_map(
         blk_fn, mesh=mesh, in_specs=(), out_specs=P("clients"),
-        check_rep=False))())
+        check_vma=False))())
     np.testing.assert_array_equal(got[:n], np.asarray(full))
     assert not got[n:].any()                   # pad lanes never available
 
@@ -143,7 +144,7 @@ def test_bernoulli_step_block_forces_nonempty():
                                        axis="clients")
         return mask_blk
 
-    got = np.asarray(jax.jit(shard_map(
+    got = np.asarray(jax.jit(jax.shard_map(
         blk_fn, mesh=mesh, in_specs=(), out_specs=P("clients"),
-        check_rep=False))())
+        check_vma=False))())
     np.testing.assert_array_equal(got, np.asarray(full))

@@ -23,7 +23,6 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from conftest import assert_cell_parity, parity_spec, run_cell
@@ -108,11 +107,11 @@ def test_sharded_topk_mask_matches_topk_mask(method):
     n = 24 * shards
     k_max = 7
 
-    f = jax.jit(shard_map(
+    f = jax.jit(jax.shard_map(
         lambda s, a, k: sharded_topk_mask(s, a, k, "clients", k_max,
                                           method=method),
         mesh=mesh, in_specs=(P("clients"), P("clients"), P()),
-        out_specs=P("clients"), check_rep=False))
+        out_specs=P("clients"), check_vma=False))
 
     def check(scores, avail, k, label):
         want = np.asarray(_topk_mask(jnp.asarray(scores), jnp.asarray(avail),
@@ -149,11 +148,11 @@ def test_sharded_cohort_ids_matches_reference(method):
     n = 16 * shards
     cohort = 6
 
-    f = jax.jit(shard_map(
+    f = jax.jit(jax.shard_map(
         lambda m: sharded_cohort_ids_from_mask(m, cohort, "clients", n,
                                                method=method),
         mesh=mesh, in_specs=P("clients"), out_specs=(P(), P()),
-        check_rep=False))
+        check_vma=False))
 
     rng = np.random.default_rng(1)
     for _ in range(20):
@@ -193,10 +192,10 @@ def test_cohort_ids_all_zero_mask_is_all_invalid():
     mesh = _client_mesh()
     shards = mesh.shape["clients"]
     n2 = 8 * shards
-    f = jax.jit(shard_map(
+    f = jax.jit(jax.shard_map(
         lambda m: sharded_cohort_ids_from_mask(m, k, "clients", n2),
         mesh=mesh, in_specs=P("clients"), out_specs=(P(), P()),
-        check_rep=False))
+        check_vma=False))
     ids2, valid2 = f(jnp.zeros(n2, bool))
     assert not np.asarray(valid2).any()
     np.testing.assert_array_equal(np.asarray(ids2), [n2 - 1] * k)

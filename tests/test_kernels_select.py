@@ -148,11 +148,25 @@ def test_fed_select_mask_interpret_bitwise(n):
         assert_bitwise(got, want, f"n={n} k={k}")
 
 
-def test_bitonic_sort_is_exact_permutation():
+def test_order_key_preserves_float_order():
+    # the kernel's bisection compares int32 keys; they must order and tie
+    # exactly as the backend's own f32 compares do (−0.0 == +0.0, ±inf,
+    # the unavailable sentinel; denormals as the backend treats them)
     rng = np.random.default_rng(3)
-    x = jnp.asarray(rng.normal(size=256).astype(np.float32))
-    got = jax.jit(fs._bitonic_sort)(x)
-    assert_bitwise(got, jnp.sort(x), "bitonic vs jnp.sort")
+    special = np.array([0.0, -0.0, 1e-45, -1e-45, np.inf, -np.inf,
+                        ref.SELECT_NEG, 1.0, -1.0], np.float32)
+    x = jnp.asarray(np.concatenate(
+        [special, rng.normal(size=247).astype(np.float32)]))
+
+    @jax.jit
+    def orders(v):
+        key = fs._order_key(v)
+        return (key[:, None] < key[None, :], v[:, None] < v[None, :],
+                key[:, None] == key[None, :], v[:, None] == v[None, :])
+
+    key_lt, lt, key_eq, eq = orders(x)
+    np.testing.assert_array_equal(np.asarray(key_lt), np.asarray(lt))
+    np.testing.assert_array_equal(np.asarray(key_eq), np.asarray(eq))
 
 
 # ---------------------------------------------------------------------------
@@ -221,3 +235,35 @@ def test_runspec_rejects_pallas_with_mesh():
         RunSpec(select_impl="pallas", mesh_shape=(1,)).resolved()
     with pytest.raises(ValueError, match="select_impl"):
         RunSpec(select_impl="fast").resolved()
+
+
+# ---------------------------------------------------------------------------
+# The selection path that ran is reported, never silently swapped.
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("engine,aggregation", [("device", "sync"),
+                                                ("host", "sync"),
+                                                ("device", "buffered")])
+@pytest.mark.parametrize("override,want", [(None, "ref"),
+                                           ("interpret", "interpret")])
+def test_final_metrics_report_select_path(monkeypatch, engine, aggregation,
+                                          override, want):
+    from repro.sim import RunSpec, run_scenario
+    monkeypatch.setattr(fs, "AUTODETECT_OVERRIDE", override)
+    silent = lambda *a, **k: None      # noqa: E731
+    for impl, expect in (("xla", "xla"), ("pallas", want)):
+        spec = RunSpec(scenario="scarce", rounds=2, eval_every=2,
+                       engine=engine, aggregation=aggregation,
+                       select_impl=impl)
+        res = run_scenario(spec, log_fn=silent)
+        assert res.final_metrics["select_path"] == expect, (impl, engine)
+
+
+def test_dispatch_mode_reports_the_reference_beyond_the_kernel_cap(
+        monkeypatch):
+    # on a TPU the compiled kernel runs up to MAX_KERNEL_N; past it the
+    # fused reference runs, and dispatch_mode says so
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert fs.dispatch_mode(fs.MAX_KERNEL_N) == "compiled"
+    assert fs.dispatch_mode(fs.MAX_KERNEL_N + 1) == "ref"
+    assert fs.dispatch_mode(10, interpret=True) == "interpret"
