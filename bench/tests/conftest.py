@@ -1,0 +1,11 @@
+"""The harness's tests run on the CPU from the repository root:
+
+    python -m pytest bench/tests
+"""
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+for path in (str(ROOT), str(ROOT / "src")):
+    if path not in sys.path:
+        sys.path.insert(0, path)
