@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .keys import NONEMPTY
+from .spans import collective_scope
 
 
 def force_nonempty(mask: jnp.ndarray, q: jnp.ndarray,
@@ -58,10 +59,13 @@ def force_nonempty_block(mask_blk: jnp.ndarray, cand_blk: jnp.ndarray,
     """
     v = cand_blk.max()
     j = jnp.argmax(cand_blk).astype(jnp.int32)
-    vs = jax.lax.all_gather(v, axis)                    # (D,) tiny
-    js = jax.lax.all_gather(off + j, axis)
+    with collective_scope(axis):
+        vs = jax.lax.all_gather(v, axis)                # (D,) tiny
+        js = jax.lax.all_gather(off + j, axis)
     idx = js[jnp.argmax(vs)]
-    nonempty = jax.lax.psum(mask_blk.sum().astype(jnp.int32), axis) > 0
+    n_blk = mask_blk.sum().astype(jnp.int32)
+    with collective_scope(axis):
+        nonempty = jax.lax.psum(n_blk, axis) > 0
     ids = off + jnp.arange(mask_blk.shape[0], dtype=jnp.int32)
     return jnp.where(nonempty, mask_blk, ids == idx)
 

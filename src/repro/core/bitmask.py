@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .spans import collective_scope
+
 __all__ = ["all_gather_bits", "n_words", "pack_bits", "unpack_bits",
            "unpack_bits_np"]
 
@@ -80,6 +82,9 @@ def all_gather_bits(mask_blk: jnp.ndarray, axis: str, n: int) -> jnp.ndarray:
     """
     n_local = mask_blk.shape[0]
     if n_local % _WORD:
-        return jax.lax.all_gather(mask_blk, axis, tiled=True)[:n]
-    words = jax.lax.all_gather(pack_bits(mask_blk), axis, tiled=True)
+        with collective_scope(axis):
+            return jax.lax.all_gather(mask_blk, axis, tiled=True)[:n]
+    packed = pack_bits(mask_blk)
+    with collective_scope(axis):
+        words = jax.lax.all_gather(packed, axis, tiled=True)
     return unpack_bits(words, words.shape[0] * _WORD)[:n]

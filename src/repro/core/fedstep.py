@@ -32,6 +32,7 @@ import jax.numpy as jnp
 
 from .aggregation import (streaming_aggregate_add, streaming_aggregate_init,
                           weighted_aggregate)
+from .spans import collective_scope, scope
 from ..optim.optimizers import Optimizer, apply_updates
 
 
@@ -152,49 +153,62 @@ def make_fed_round(loss_fn: Callable, server_opt: Optimizer, *,
             return jax.lax.dynamic_slice_in_dim(
                 full, jax.lax.axis_index(model_axis) * blk, blk, axis=d)
 
+        def _reduce_block(full, blk_like, spec):
+            blk = _slice_block(full, blk_like, spec)
+            with collective_scope(cohort_axis):
+                return jax.lax.psum(blk, cohort_axis)
+
         def fed_round_sharded(params, opt_state, cohort_batch, weights,
                               client_lr, slot_mask):
             if model_axis is None:
                 p_full = params
             else:
-                p_full = jax.tree.map(_gather_full, params, param_specs)
-            deltas, losses, gnorms = jax.vmap(
-                lambda b: _local_sgd(loss_fn, p_full, b, client_lr, remat,
-                                     prox_mu=prox_mu)
-            )(cohort_batch)
-            loss = jax.lax.psum((losses * slot_mask).sum(),
-                                cohort_axis) / cohort_slots
-            gnorm = jax.lax.psum((gnorms * slot_mask).sum(),
-                                 cohort_axis) / cohort_slots
-            if model_axis is None:
-                delta = jax.lax.psum(weighted_aggregate(deltas, weights),
-                                     cohort_axis)
-                dnorm = jnp.sqrt(sum(jnp.sum(x * x).astype(jnp.float32)
-                                     for x in jax.tree.leaves(delta)))
-            else:
+                with collective_scope(model_axis):
+                    p_full = jax.tree.map(_gather_full, params, param_specs)
+            with scope("local_sgd"):
+                deltas, losses, gnorms = jax.vmap(
+                    lambda b: _local_sgd(loss_fn, p_full, b, client_lr,
+                                         remat, prox_mu=prox_mu)
+                )(cohort_batch)
+            with scope("aggregate"):
+                loss_sum = (losses * slot_mask).sum()
+                with collective_scope(cohort_axis):
+                    loss = jax.lax.psum(loss_sum, cohort_axis) / cohort_slots
+                gnorm_sum = (gnorms * slot_mask).sum()
+                with collective_scope(cohort_axis):
+                    gnorm = jax.lax.psum(gnorm_sum,
+                                         cohort_axis) / cohort_slots
                 delta_full = weighted_aggregate(deltas, weights)
-                delta = jax.tree.map(
-                    lambda f, b, s: jax.lax.psum(_slice_block(f, b, s),
-                                                 cohort_axis),
-                    delta_full, params, param_specs)
-                # per-block partial sums of squares; replicated leaves are
-                # held on every model shard and must be counted once
-                d_leaves = jax.tree.leaves(delta)
-                d_specs = jax.tree.structure(delta).flatten_up_to(param_specs)
-                sq_sharded = sum(
-                    (jnp.sum(x * x).astype(jnp.float32)
-                     for x, s in zip(d_leaves, d_specs)
-                     if _model_dim(s) is not None),
-                    jnp.zeros((), jnp.float32))
-                sq_repl = sum(
-                    (jnp.sum(x * x).astype(jnp.float32)
-                     for x, s in zip(d_leaves, d_specs)
-                     if _model_dim(s) is None),
-                    jnp.zeros((), jnp.float32))
-                dnorm = jnp.sqrt(
-                    sq_repl + jax.lax.psum(sq_sharded, model_axis))
-            updates, opt_state = server_opt.update(delta, opt_state, params)
-            params = apply_updates(params, updates)
+                if model_axis is None:
+                    with collective_scope(cohort_axis):
+                        delta = jax.lax.psum(delta_full, cohort_axis)
+                    dnorm = jnp.sqrt(sum(jnp.sum(x * x).astype(jnp.float32)
+                                         for x in jax.tree.leaves(delta)))
+                else:
+                    delta = jax.tree.map(_reduce_block, delta_full, params,
+                                         param_specs)
+                    # per-block partial sums of squares; replicated leaves
+                    # are held on every model shard and must be counted once
+                    d_leaves = jax.tree.leaves(delta)
+                    d_specs = jax.tree.structure(delta).flatten_up_to(
+                        param_specs)
+                    sq_sharded = sum(
+                        (jnp.sum(x * x).astype(jnp.float32)
+                         for x, s in zip(d_leaves, d_specs)
+                         if _model_dim(s) is not None),
+                        jnp.zeros((), jnp.float32))
+                    sq_repl = sum(
+                        (jnp.sum(x * x).astype(jnp.float32)
+                         for x, s in zip(d_leaves, d_specs)
+                         if _model_dim(s) is None),
+                        jnp.zeros((), jnp.float32))
+                    with collective_scope(model_axis):
+                        sq_sharded = jax.lax.psum(sq_sharded, model_axis)
+                    dnorm = jnp.sqrt(sq_repl + sq_sharded)
+            with scope("server_update"):
+                updates, opt_state = server_opt.update(delta, opt_state,
+                                                       params)
+                params = apply_updates(params, updates)
             return params, opt_state, RoundMetrics(loss=loss,
                                                    delta_norm=dnorm,
                                                    grad_norm=gnorm)
@@ -203,37 +217,45 @@ def make_fed_round(loss_fn: Callable, server_opt: Optimizer, *,
 
     def fed_round(params, opt_state, cohort_batch, weights, client_lr):
         if mode == "parallel":
-            deltas, losses, gnorms = jax.vmap(
-                lambda b: _local_sgd(loss_fn, params, b, client_lr, remat,
-                                     prox_mu=prox_mu)
-            )(cohort_batch)
-            delta = weighted_aggregate(deltas, weights)
-            loss = losses.mean()
-            gnorm = gnorms.mean()
+            with scope("local_sgd"):
+                deltas, losses, gnorms = jax.vmap(
+                    lambda b: _local_sgd(loss_fn, params, b, client_lr,
+                                         remat, prox_mu=prox_mu)
+                )(cohort_batch)
+            with scope("aggregate"):
+                delta = weighted_aggregate(deltas, weights)
         else:
-            acc0 = streaming_aggregate_init(params, acc_dtype)
+            with scope("aggregate"):
+                acc0 = streaming_aggregate_init(params, acc_dtype)
 
             def body(acc, xs):
                 batch_k, w_k = xs
-                v_k, loss_k, gnorm_k = _local_sgd(loss_fn, params, batch_k,
-                                                  client_lr, remat,
-                                                  shardings=param_shardings,
-                                                  prox_mu=prox_mu)
-                acc = streaming_aggregate_add(acc, v_k, w_k)
-                return _constrain(acc, param_shardings), (loss_k, gnorm_k)
+                with scope("local_sgd"):
+                    v_k, loss_k, gnorm_k = _local_sgd(
+                        loss_fn, params, batch_k, client_lr, remat,
+                        shardings=param_shardings, prox_mu=prox_mu)
+                with scope("aggregate"):
+                    acc = streaming_aggregate_add(acc, v_k, w_k)
+                    acc = _constrain(acc, param_shardings)
+                return acc, (loss_k, gnorm_k)
 
             acc, (losses, gnorms) = jax.lax.scan(body, acc0, (cohort_batch, weights))
-            delta = jax.tree.map(lambda a, p_: a.astype(p_.dtype), acc, params)
+            with scope("aggregate"):
+                delta = jax.tree.map(lambda a, p_: a.astype(p_.dtype), acc,
+                                     params)
+
+        with scope("aggregate"):
             loss = losses.mean()
             gnorm = gnorms.mean()
-
-        # self-dot per leaf WITHOUT reshaping: vdot flattens to 1-D, and a
-        # reshape of a sharded tensor cannot preserve its sharding — XLA
-        # all-gathers the full tree (observed: +60 GB/device on an 8B model)
-        dnorm = jnp.sqrt(sum(jnp.sum(x * x).astype(jnp.float32)
-                             for x in jax.tree.leaves(delta)))
-        updates, opt_state = server_opt.update(delta, opt_state, params)
-        params = apply_updates(params, updates)
+            # self-dot per leaf WITHOUT reshaping: vdot flattens to 1-D, and
+            # a reshape of a sharded tensor cannot preserve its sharding —
+            # XLA all-gathers the full tree (observed: +60 GB/device on an
+            # 8B model)
+            dnorm = jnp.sqrt(sum(jnp.sum(x * x).astype(jnp.float32)
+                                 for x in jax.tree.leaves(delta)))
+        with scope("server_update"):
+            updates, opt_state = server_opt.update(delta, opt_state, params)
+            params = apply_updates(params, updates)
         return params, opt_state, RoundMetrics(loss=loss, delta_norm=dnorm,
                                                grad_norm=gnorm)
 

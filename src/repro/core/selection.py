@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 
 from .hfun import marginal_utility
+from .spans import collective_scope, scope
 
 # Score sentinel for unavailable clients — low enough that no real score
 # (utility, Gumbel, uniform) reaches it, so unavailable clients rank last.
@@ -58,13 +59,16 @@ def _topk_mask(scores: jnp.ndarray, avail: jnp.ndarray, k: jnp.ndarray) -> jnp.n
     between the two via ``RunSpec.select_impl``.
     """
     n = scores.shape[0]
-    masked = jnp.where(avail, scores, _NEG)
-    # Rank positions by score (descending); position i selected iff its rank
-    # < k and it is available.  Stable w.r.t. ties via argsort.
-    order = jnp.argsort(-masked)            # indices, best first
-    ranks = jnp.zeros((n,), jnp.int32).at[order].set(jnp.arange(n, dtype=jnp.int32))
-    k_eff = jnp.minimum(k.astype(jnp.int32), avail.sum().astype(jnp.int32))
-    return (ranks < k_eff) & avail
+    with scope("topk"):
+        masked = jnp.where(avail, scores, _NEG)
+        # Rank positions by score (descending); position i selected iff its
+        # rank < k and it is available.  Stable w.r.t. ties via argsort.
+        order = jnp.argsort(-masked)            # indices, best first
+        ranks = jnp.zeros((n,), jnp.int32).at[order].set(
+            jnp.arange(n, dtype=jnp.int32))
+        k_eff = jnp.minimum(k.astype(jnp.int32),
+                            avail.sum().astype(jnp.int32))
+        return (ranks < k_eff) & avail
 
 
 def f3ast_select(avail: jnp.ndarray, k: jnp.ndarray, p: jnp.ndarray,
@@ -186,8 +190,9 @@ def _stream_topk_candidates(vals, gids, axis: str, k_max: int):
         for s in range(d.bit_length() - 1):
             bit = 1 << s
             perm = [(j, j ^ bit) for j in range(d)]
-            ov = jax.lax.ppermute(vals, axis, perm)
-            og = jax.lax.ppermute(gids, axis, perm)
+            with collective_scope(axis):
+                ov = jax.lax.ppermute(vals, axis, perm)
+                og = jax.lax.ppermute(gids, axis, perm)
             length = min(int(k_max), 2 * length)
             vals, gids = _merge_desc(vals, gids, ov, og, length)
         return vals, gids
@@ -195,8 +200,9 @@ def _stream_topk_candidates(vals, gids, axis: str, k_max: int):
     perm = [(j, (j + 1) % d) for j in range(d)]
     buf_v, buf_g = vals, gids
     for step in range(1, d):
-        buf_v = jax.lax.ppermute(buf_v, axis, perm)
-        buf_g = jax.lax.ppermute(buf_g, axis, perm)
+        with collective_scope(axis):
+            buf_v = jax.lax.ppermute(buf_v, axis, perm)
+            buf_g = jax.lax.ppermute(buf_g, axis, perm)
         keep = min(int(k_max), kk * (step + 1))
         vals, gids = _merge_desc(vals, gids, buf_v, buf_g, keep)
     return vals, gids
@@ -232,13 +238,19 @@ def sharded_topk_mask(scores: jnp.ndarray, avail: jnp.ndarray,
     if method not in TOPK_IMPLS:
         raise ValueError(f"unknown sharded top-k method {method!r}; "
                          f"known: {TOPK_IMPLS}")
+    with scope("topk"):
+        return _sharded_topk_cut(scores, avail, k, axis, k_max, method)
+
+
+def _sharded_topk_cut(scores, avail, k, axis: str, k_max: int, method: str):
     n_local = scores.shape[0]
     i = jax.lax.axis_index(axis)
     masked = jnp.where(avail, scores, _NEG)
     kk = min(int(k_max), n_local)
     vals, loc = jax.lax.top_k(masked, kk)
     gids = (loc + i * n_local).astype(jnp.int32)
-    n_avail = jax.lax.psum(avail.sum().astype(jnp.int32), axis)
+    with collective_scope(axis):
+        n_avail = jax.lax.psum(avail.sum().astype(jnp.int32), axis)
     k_eff = jnp.minimum(k.astype(jnp.int32), n_avail)
     if method == "stream":
         top_v, top_g = _stream_topk_candidates(vals, gids, axis, k_max)
@@ -249,8 +261,9 @@ def sharded_topk_mask(scores: jnp.ndarray, avail: jnp.ndarray,
         hit = jnp.zeros((n_local,), bool).at[
             jnp.where(in_shard, loc_ids, 0)].max(in_shard)
         return hit & avail
-    all_vals = jax.lax.all_gather(vals, axis, tiled=True)
-    all_gids = jax.lax.all_gather(gids, axis, tiled=True)
+    with collective_scope(axis):
+        all_vals = jax.lax.all_gather(vals, axis, tiled=True)
+        all_gids = jax.lax.all_gather(gids, axis, tiled=True)
     _, sorted_gids = jax.lax.sort((-all_vals, all_gids), num_keys=2)
     take = jnp.arange(sorted_gids.shape[0], dtype=jnp.int32) < k_eff
     sel_gids = jnp.where(take, sorted_gids, -1)
@@ -291,14 +304,16 @@ def _stream_min_ids(ids, axis: str, keep_max: int):
         length = kk
         for s in range(d.bit_length() - 1):
             perm = [(j, j ^ (1 << s)) for j in range(d)]
-            other = jax.lax.ppermute(ids, axis, perm)
+            with collective_scope(axis):
+                other = jax.lax.ppermute(ids, axis, perm)
             length = min(int(keep_max), 2 * length)
             ids = merge(ids, other, length)
         return ids
     perm = [(j, (j + 1) % d) for j in range(d)]
     buf = ids
     for step in range(1, d):
-        buf = jax.lax.ppermute(buf, axis, perm)
+        with collective_scope(axis):
+            buf = jax.lax.ppermute(buf, axis, perm)
         ids = merge(ids, buf, min(int(keep_max), kk * (step + 1)))
     return ids
 
@@ -334,7 +349,9 @@ def sharded_cohort_ids_from_mask(mask: jnp.ndarray, cohort_size: int,
             [cand, jnp.full((max(0, cohort_size - cand.shape[0]),), n_total,
                             cand.dtype)])
     else:
-        cand = jnp.sort(jax.lax.all_gather(ranked[:kk], axis, tiled=True))
+        with collective_scope(axis):
+            gathered = jax.lax.all_gather(ranked[:kk], axis, tiled=True)
+        cand = jnp.sort(gathered)
     ids = cand[:cohort_size]
     valid = ids < n_total
     first = jnp.minimum(cand[0], n_total - 1)

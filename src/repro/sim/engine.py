@@ -51,6 +51,7 @@ from ..checkpoint import save_checkpoint
 from ..core.bitmask import pack_bits, unpack_bits_np
 from ..core.fedstep import make_fed_round
 from ..core.selection import cohort_ids_from_mask
+from ..core.spans import scope
 from ..core.strategies import (SelectCtx, get_strategy_entry, make_strategy,
                                resolve_strategy, select_path, strategy_rates)
 from ..data import CohortSampler
@@ -101,9 +102,21 @@ class RoundStream(NamedTuple):
 
 def _unpack_stream(out_np: "RoundStream", n: int) -> "RoundStream":
     """Driver-side decode of one chunk's streams: packed masks → (C, n)
-    bool (bits past ``n`` — client-dim padding — are never set)."""
-    return out_np._replace(sel_mask=unpack_bits_np(out_np.sel_mask, n),
-                           completed=unpack_bits_np(out_np.completed, n))
+    bool (bits past ``n`` — client-dim padding — are never set).  Traced
+    as ``stream_decode``; ``bytes`` counts the bool bytes it produces."""
+    rows = int(np.prod(out_np.sel_mask.shape[:-1]))
+    with jax.profiler.TraceAnnotation("stream_decode", clients=n,
+                                      bytes=2 * rows * n):
+        return out_np._replace(sel_mask=unpack_bits_np(out_np.sel_mask, n),
+                               completed=unpack_bits_np(out_np.completed, n))
+
+
+def _pull(out) -> "RoundStream":
+    """Device → host copy of one chunk's streams, traced as
+    ``stream_pull``; ``bytes`` counts the (packed) bytes pulled."""
+    nbytes = sum(int(x.nbytes) for x in jax.tree.leaves(out))
+    with jax.profiler.TraceAnnotation("stream_pull", bytes=nbytes):
+        return jax.tree.map(np.asarray, out)
 
 
 def _staged_nbytes(staged) -> int:
@@ -140,6 +153,10 @@ class DeviceEngine:
         self.selection_comm_bytes_per_round = 0   # single device: no comm
         trivial = completion is None or completion.trivial
 
+        def complete_draw(key, t, sel_mask):
+            with scope("complete"):
+                return completion.sample(key, t, sel_mask)
+
         def cohort_batch(key, ids):
             if synth:
                 return synth_cohort_batch(staged, key, ids, local_steps,
@@ -153,31 +170,37 @@ class DeviceEngine:
             # main stream: completion="always" stays bit-identical.
             key, k_av, k_sel, k_bud, k_batch = jax.random.split(carry.key, 5)
             k_comp = jax.random.fold_in(k_sel, KEY_FOLD)
-            avail_state, avail = avail_model.step(k_av, carry.avail_state, t)
-            k_t = jnp.minimum(budget.sample(k_bud, t),
-                              jnp.asarray(k_cap, jnp.int32))
+            with scope("avail"):
+                avail_state, avail = avail_model.step(k_av,
+                                                      carry.avail_state, t)
+            with scope("budget"):
+                k_t = jnp.minimum(budget.sample(k_bud, t),
+                                  jnp.asarray(k_cap, jnp.int32))
             complete_fn = (None if trivial else
-                           lambda m: completion.sample(k_comp, t, m))
-            sel_mask, w_full, algo_state = strategy.select(
-                carry.algo_state, k_sel, avail, k_t,
-                SelectCtx(t=t, complete=complete_fn))
+                           lambda m: complete_draw(k_comp, t, m))
+            with scope("select"):
+                sel_mask, w_full, algo_state = strategy.select(
+                    carry.algo_state, k_sel, avail, k_t,
+                    SelectCtx(t=t, complete=complete_fn))
             # same pure draw as inside select — identical completed mask
             completed = sel_mask if trivial else complete_fn(sel_mask)
-            ids, valid = cohort_ids_from_mask(sel_mask, budget.k_max)
-            batch = cohort_batch(k_batch, ids)
-            w = w_full[ids] * valid
-            if not trivial:
-                # dropped slots contribute nothing even if the strategy's
-                # finalize ignored the completion hook
-                w = w * completed[ids]
+            with scope("cohort"):
+                ids, valid = cohort_ids_from_mask(sel_mask, budget.k_max)
+                batch = cohort_batch(k_batch, ids)
+                w = w_full[ids] * valid
+                if not trivial:
+                    # dropped slots contribute nothing even if the
+                    # strategy's finalize ignored the completion hook
+                    w = w * completed[ids]
             params, opt_state, m = fed_round(
                 carry.params, carry.opt_state, batch, w,
                 jnp.asarray(client_lr, jnp.float32))
-            out = RoundStream(sel_mask=pack_bits(sel_mask),
-                              completed=pack_bits(completed),
-                              k_t=k_t,
-                              n_available=avail.sum().astype(jnp.int32),
-                              train_loss=m.loss, delta_norm=m.delta_norm)
+            with scope("stream"):
+                out = RoundStream(sel_mask=pack_bits(sel_mask),
+                                  completed=pack_bits(completed),
+                                  k_t=k_t,
+                                  n_available=avail.sum().astype(jnp.int32),
+                                  train_loss=m.loss, delta_norm=m.delta_norm)
             return EngineCarry(key, params, opt_state, algo_state,
                                avail_state), out
 
@@ -211,13 +234,17 @@ class DeviceEngine:
 
     def chunk(self, carry, ts, k_cap=None):
         """Advance one chunk of rounds; returns (carry', RoundStream)."""
-        if k_cap is None:
-            return self._chunk(carry, ts, self._k_max_dev)
-        return self._chunk(carry, ts, jnp.asarray(k_cap, jnp.int32))
+        k_cap = (self._k_max_dev if k_cap is None
+                 else jnp.asarray(k_cap, jnp.int32))
+        with jax.profiler.TraceAnnotation("chunk_dispatch", rounds=len(ts)):
+            return self._chunk(carry, ts, k_cap)
 
     def vmapped_chunk(self, carries, ts, k_caps):
         """Batched chunk over the leading cell axis of ``carries``/``k_caps``."""
-        return self._vchunk(carries, ts, jnp.asarray(k_caps, jnp.int32))
+        k_caps = jnp.asarray(k_caps, jnp.int32)
+        with jax.profiler.TraceAnnotation(
+                "chunk_dispatch", rounds=len(ts) * int(k_caps.shape[0])):
+            return self._vchunk(carries, ts, k_caps)
 
 
 def build_engine(scenario: Union[str, Scenario], algo_name: str = "f3ast", *,
@@ -439,7 +466,7 @@ def run_scenario_device(scenario: Union[str, Scenario],
                 carry, out = engine.chunk(carry, ts)
             # One host↔device sync per chunk: pull the streamed metrics
             # (masks cross packed — unpack once here, see RoundStream).
-            out_np = _unpack_stream(jax.tree.map(np.asarray, out), n_real)
+            out_np = _unpack_stream(_pull(out), n_real)
             if t_first_chunk is None:
                 t_first_chunk = time.time()
             streams.append(out_np)
@@ -449,10 +476,11 @@ def run_scenario_device(scenario: Union[str, Scenario],
             do_eval = (t1 == rounds
                        or any(t % eval_every == 0 for t in range(t0, t1)))
             if do_eval:
-                test_loss = float(ctx["eval_loss"](carry.params,
-                                                   ctx["test_batch"]))
-                test_acc = float(ctx["eval_acc"](carry.params,
-                                                 ctx["test_batch"]))
+                with jax.profiler.TraceAnnotation("eval"):
+                    test_loss = float(ctx["eval_loss"](carry.params,
+                                                       ctx["test_batch"]))
+                    test_acc = float(ctx["eval_acc"](carry.params,
+                                                     ctx["test_batch"]))
                 history.append(dict(round=t1 - 1,
                                     train_loss=float(out_np.train_loss[-1]),
                                     test_loss=test_loss, test_acc=test_acc,
@@ -466,23 +494,27 @@ def run_scenario_device(scenario: Union[str, Scenario],
                        f"done={history[-1]['n_completed']} "
                        f"avail={history[-1]['n_available']}")
             if metrics_file:
-                for i, t in enumerate(range(t0, t1)):
-                    record = dict(scenario=sc.name, algorithm=algo_label,
-                                  round=t, k_t=int(out_np.k_t[i]),
-                                  n_available=int(out_np.n_available[i]),
-                                  n_selected=int(out_np.sel_mask[i].sum()),
-                                  n_completed=int(out_np.completed[i].sum()),
-                                  train_loss=float(out_np.train_loss[i]),
-                                  delta_norm=float(out_np.delta_norm[i]))
-                    if do_eval and t == t1 - 1:
-                        record["test_loss"] = test_loss
-                        record["test_acc"] = test_acc
-                    metrics_file.write(json.dumps(record) + "\n")
-                metrics_file.flush()
+                with jax.profiler.TraceAnnotation("metrics_write"):
+                    for i, t in enumerate(range(t0, t1)):
+                        record = dict(
+                            scenario=sc.name, algorithm=algo_label,
+                            round=t, k_t=int(out_np.k_t[i]),
+                            n_available=int(out_np.n_available[i]),
+                            n_selected=int(out_np.sel_mask[i].sum()),
+                            n_completed=int(out_np.completed[i].sum()),
+                            train_loss=float(out_np.train_loss[i]),
+                            delta_norm=float(out_np.delta_norm[i]))
+                        if do_eval and t == t1 - 1:
+                            record["test_loss"] = test_loss
+                            record["test_acc"] = test_acc
+                        metrics_file.write(json.dumps(record) + "\n")
+                    metrics_file.flush()
             if ckpt_dir:
-                save_checkpoint(ckpt_dir, t1,
-                                {"params": carry.params,
-                                 "rates": _final_rates(engine, carry, n_real)})
+                with jax.profiler.TraceAnnotation("checkpoint"):
+                    save_checkpoint(ckpt_dir, t1,
+                                    {"params": carry.params,
+                                     "rates": _final_rates(engine, carry,
+                                                           n_real)})
     finally:
         if metrics_file:
             metrics_file.close()
@@ -556,8 +588,7 @@ def run_cells_vmapped(scenario: Union[str, Scenario],
     for (t0, t1) in _chunk_spans(rounds, chunk_size):
         ts = jnp.arange(t0, t1, dtype=jnp.int32)
         carries, out = engine.vmapped_chunk(carries, ts, k_caps_arr)
-        streams.append(_unpack_stream(jax.tree.map(np.asarray, out),
-                                      engine.n_clients))
+        streams.append(_unpack_stream(_pull(out), engine.n_clients))
         if t_first_chunk is None:
             t_first_chunk = time.time()
     t_end = time.time()

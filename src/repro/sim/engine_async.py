@@ -62,6 +62,7 @@ import numpy as np
 from ..checkpoint import save_checkpoint
 from ..core.fedstep import make_fed_round
 from ..core.selection import cohort_ids_from_mask
+from ..core.spans import scope
 from ..core.strategies import (SelectCtx, get_strategy_entry, make_strategy,
                                resolve_strategy, select_path, strategy_rates)
 from ..data import CohortSampler
@@ -69,6 +70,7 @@ from ..data.pipeline import staged_cohort_batch
 from ..optim import make_optimizer
 from ..core.keys import COMPLETION as KEY_FOLD
 from ..core.sanitize import guard_transfers
+from .engine import _pull
 from .scenario import Scenario, get_scenario
 
 __all__ = ["STALENESS_DISCOUNTS", "ArrivalPool", "AsyncCarry", "AsyncEngine",
@@ -260,43 +262,50 @@ class AsyncEngine:
             # the main avail/select/budget/batch streams are untouched.
             key, k_av, k_sel, k_bud, k_batch = jax.random.split(carry.key, 5)
             k_arr = jax.random.fold_in(k_sel, KEY_FOLD)
-            avail_state, avail = avail_model.step(k_av, carry.avail_state, t)
-            k_t = budget.sample(k_bud, t)
-            sel_mask, w_full, algo_state = strategy.select(
-                carry.algo_state, k_sel, avail, k_t, SelectCtx(t=t))
-            # dispatch the selected cohort into the pending pool
-            ids, valid = cohort_ids_from_mask(sel_mask, budget.k_max)
-            lat = arrival.latencies(k_arr, t)
-            t_f = jnp.asarray(t, jnp.float32)
-            new = ArrivalPool(
-                time=jnp.where(valid, t_f + lat[ids], jnp.inf),
-                cid=jnp.where(valid, ids, n).astype(jnp.int32),
-                round=jnp.where(valid, jnp.asarray(t, jnp.int32), 0),
-                valid=valid)
-            pool, n_overflow = pool_insert(carry.pool, new)
-            # flush: aggregate the first M pending arrivals
-            pool, buf_ids, buf_valid, buf_stale = pool_flush(
-                pool, self.buffer_size, t, n)
-            weights = staleness_weights(buf_stale, buf_valid,
-                                        self.staleness_power,
-                                        self.staleness_discount)
-            batch = staged_cohort_batch(staged, k_batch, buf_ids, local_steps,
-                                        local_batch)
+            with scope("avail"):
+                avail_state, avail = avail_model.step(k_av,
+                                                      carry.avail_state, t)
+            with scope("budget"):
+                k_t = budget.sample(k_bud, t)
+            with scope("select"):
+                sel_mask, w_full, algo_state = strategy.select(
+                    carry.algo_state, k_sel, avail, k_t, SelectCtx(t=t))
+            with scope("cohort"):
+                # dispatch the selected cohort into the pending pool
+                ids, valid = cohort_ids_from_mask(sel_mask, budget.k_max)
+                lat = arrival.latencies(k_arr, t)
+                t_f = jnp.asarray(t, jnp.float32)
+                new = ArrivalPool(
+                    time=jnp.where(valid, t_f + lat[ids], jnp.inf),
+                    cid=jnp.where(valid, ids, n).astype(jnp.int32),
+                    round=jnp.where(valid, jnp.asarray(t, jnp.int32), 0),
+                    valid=valid)
+                pool, n_overflow = pool_insert(carry.pool, new)
+                # flush: aggregate the first M pending arrivals
+                pool, buf_ids, buf_valid, buf_stale = pool_flush(
+                    pool, self.buffer_size, t, n)
+                weights = staleness_weights(buf_stale, buf_valid,
+                                            self.staleness_power,
+                                            self.staleness_discount)
+                batch = staged_cohort_batch(staged, k_batch, buf_ids,
+                                            local_steps, local_batch)
             params, opt_state, m = fed_round(
                 carry.params, carry.opt_state, batch, weights,
                 jnp.asarray(client_lr, jnp.float32))
-            n_buf = buf_valid.sum().astype(jnp.int32)
-            mean_stale = jnp.where(
-                n_buf > 0,
-                (buf_stale * buf_valid).sum() / jnp.maximum(n_buf, 1),
-                0.0).astype(jnp.float32)
-            out = AsyncStream(sel_mask=sel_mask, buf_ids=buf_ids,
-                              buf_valid=buf_valid, buf_staleness=buf_stale,
-                              buf_weights=weights, k_t=k_t,
-                              n_available=avail.sum().astype(jnp.int32),
-                              n_buffered=n_buf, mean_staleness=mean_stale,
-                              n_overflow=n_overflow,
-                              train_loss=m.loss, delta_norm=m.delta_norm)
+            with scope("stream"):
+                n_buf = buf_valid.sum().astype(jnp.int32)
+                mean_stale = jnp.where(
+                    n_buf > 0,
+                    (buf_stale * buf_valid).sum() / jnp.maximum(n_buf, 1),
+                    0.0).astype(jnp.float32)
+                out = AsyncStream(sel_mask=sel_mask, buf_ids=buf_ids,
+                                  buf_valid=buf_valid,
+                                  buf_staleness=buf_stale,
+                                  buf_weights=weights, k_t=k_t,
+                                  n_available=avail.sum().astype(jnp.int32),
+                                  n_buffered=n_buf, mean_staleness=mean_stale,
+                                  n_overflow=n_overflow,
+                                  train_loss=m.loss, delta_norm=m.delta_norm)
             return AsyncCarry(key, params, opt_state, algo_state,
                               avail_state, pool), out
 
@@ -315,7 +324,8 @@ class AsyncEngine:
 
     def chunk(self, carry, ts):
         """Advance one chunk of server steps; returns (carry', AsyncStream)."""
-        return self._chunk(carry, ts)
+        with jax.profiler.TraceAnnotation("chunk_dispatch", rounds=len(ts)):
+            return self._chunk(carry, ts)
 
 
 # ---------------------------------------------------------------------------
@@ -518,17 +528,18 @@ def _run_buffered_device(ctx, *, rounds, seed, eval_every, chunk_size,
             # transfer inside the compiled chunk raises (core.sanitize).
             with guard_transfers():
                 carry, out = engine.chunk(carry, ts)
-            out_np = jax.tree.map(np.asarray, out)
+            out_np = _pull(out)
             if t_first_chunk is None:
                 t_first_chunk = time.time()
             streams.append(out_np)
             do_eval = (t1 == rounds
                        or any(t % eval_every == 0 for t in range(t0, t1)))
             if do_eval:
-                test_loss = float(ctx["eval_loss"](carry.params,
-                                                   ctx["test_batch"]))
-                test_acc = float(ctx["eval_acc"](carry.params,
-                                                 ctx["test_batch"]))
+                with jax.profiler.TraceAnnotation("eval"):
+                    test_loss = float(ctx["eval_loss"](carry.params,
+                                                       ctx["test_batch"]))
+                    test_acc = float(ctx["eval_acc"](carry.params,
+                                                     ctx["test_batch"]))
                 history.append(dict(
                     round=t1 - 1, train_loss=float(out_np.train_loss[-1]),
                     test_loss=test_loss, test_acc=test_acc,
@@ -543,27 +554,29 @@ def _run_buffered_device(ctx, *, rounds, seed, eval_every, chunk_size,
                        f"stale={history[-1]['mean_staleness']:.1f} "
                        f"avail={history[-1]['n_available']}")
             if metrics_file:
-                for i, t in enumerate(range(t0, t1)):
-                    record = _record(
-                        sc, algo_label, t, k_t=out_np.k_t[i],
-                        n_available=out_np.n_available[i],
-                        n_selected=out_np.sel_mask[i].sum(),
-                        n_buffered=out_np.n_buffered[i],
-                        mean_staleness=out_np.mean_staleness[i],
-                        n_overflow=out_np.n_overflow[i],
-                        train_loss=out_np.train_loss[i],
-                        delta_norm=out_np.delta_norm[i])
-                    if do_eval and t == t1 - 1:
-                        record["test_loss"] = test_loss
-                        record["test_acc"] = test_acc
-                    metrics_file.write(json.dumps(record) + "\n")
-                metrics_file.flush()
+                with jax.profiler.TraceAnnotation("metrics_write"):
+                    for i, t in enumerate(range(t0, t1)):
+                        record = _record(
+                            sc, algo_label, t, k_t=out_np.k_t[i],
+                            n_available=out_np.n_available[i],
+                            n_selected=out_np.sel_mask[i].sum(),
+                            n_buffered=out_np.n_buffered[i],
+                            mean_staleness=out_np.mean_staleness[i],
+                            n_overflow=out_np.n_overflow[i],
+                            train_loss=out_np.train_loss[i],
+                            delta_norm=out_np.delta_norm[i])
+                        if do_eval and t == t1 - 1:
+                            record["test_loss"] = test_loss
+                            record["test_acc"] = test_acc
+                        metrics_file.write(json.dumps(record) + "\n")
+                    metrics_file.flush()
             if ckpt_dir:
-                save_checkpoint(ckpt_dir, t1,
-                                {"params": carry.params,
-                                 "rates": _final_rates(engine.strategy,
-                                                       carry.algo_state,
-                                                       n_real)})
+                with jax.profiler.TraceAnnotation("checkpoint"):
+                    save_checkpoint(ckpt_dir, t1,
+                                    {"params": carry.params,
+                                     "rates": _final_rates(
+                                         engine.strategy, carry.algo_state,
+                                         n_real)})
     finally:
         if metrics_file:
             metrics_file.close()

@@ -73,6 +73,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..core.bitmask import pack_bits
 from ..core.selection import sharded_cohort_ids_from_mask
+from ..core.spans import collective_scope, scope
 from ..core.strategies import SelectCtx, as_sharded
 from ..data.pipeline import SHARD_PAD_QUANTUM, synth_cohort_batch
 from ..data.synthetic import SynthTask
@@ -219,9 +220,15 @@ class ShardedEngine:
             d=n_shards, nl=nl, k=k, topk_impl=topk_impl, gathers=gathers)
 
         def gather_state(state_blk):
-            return jax.tree.map(
-                lambda leaf, f: jax.lax.all_gather(leaf, axis, tiled=True)[:n]
-                if f else leaf, state_blk, flags)
+            with collective_scope(axis):
+                return jax.tree.map(
+                    lambda leaf, f: jax.lax.all_gather(leaf, axis,
+                                                       tiled=True)[:n]
+                    if f else leaf, state_blk, flags)
+
+        def complete_draw(key, t, sel_mask):
+            with scope("complete"):
+                return completion.sample(key, t, sel_mask)
 
         def scatter_state(state_full, off):
             return jax.tree.map(
@@ -246,41 +253,70 @@ class ShardedEngine:
             i = jax.lax.axis_index(axis)
             off = i * nl
 
-            if block_avail:
-                # blockwise: each shard steps only its slice (O(nl), no
-                # (N,) intermediate, non-empty fix via tiny collectives)
-                avail_state, avail_blk = avail_model.step_block(
-                    k_av, carry.avail_state, t, off=off, n_local=nl,
-                    axis=axis)
-                avail_full = None
-                n_avail = jax.lax.psum(
-                    avail_blk.sum().astype(jnp.int32), axis)
-            else:
-                # availability: full-width replicated step, sharded state
-                full_state = gather_state(carry.avail_state)
-                new_full, avail_full = avail_model.step(k_av, full_state, t)
-                avail_state = scatter_state(new_full, off)
-                avail_blk = jax.lax.dynamic_slice_in_dim(
-                    pad_client_dim(avail_full, n_pad), off, nl)
-                n_avail = avail_full.sum().astype(jnp.int32)
+            with scope("avail"):
+                if block_avail:
+                    # blockwise: each shard steps only its slice (O(nl), no
+                    # (N,) intermediate, non-empty fix via tiny collectives)
+                    avail_state, avail_blk = avail_model.step_block(
+                        k_av, carry.avail_state, t, off=off, n_local=nl,
+                        axis=axis)
+                    avail_full = None
+                    n_blk = avail_blk.sum().astype(jnp.int32)
+                    with collective_scope(axis):
+                        n_avail = jax.lax.psum(n_blk, axis)
+                else:
+                    # availability: full-width replicated step, sharded state
+                    full_state = gather_state(carry.avail_state)
+                    new_full, avail_full = avail_model.step(k_av, full_state,
+                                                            t)
+                    avail_state = scatter_state(new_full, off)
+                    avail_blk = jax.lax.dynamic_slice_in_dim(
+                        pad_client_dim(avail_full, n_pad), off, nl)
+                    n_avail = avail_full.sum().astype(jnp.int32)
 
-            k_t = jnp.minimum(budget.sample(k_bud, t),
-                              jnp.asarray(k_cap, jnp.int32))
+            with scope("budget"):
+                k_t = jnp.minimum(budget.sample(k_bud, t),
+                                  jnp.asarray(k_cap, jnp.int32))
             complete_fn = (None if trivial else
-                           lambda m: completion.sample(k_comp, t, m))
+                           lambda m: complete_draw(k_comp, t, m))
             # avail_full is already replicated from the full-width step, so
             # the adapter skips its gather; completed_full comes back from
             # the adapter's own mask gather + completion draw — no second
             # gather, no re-draw
-            mask_blk, w_blk, algo_state, completed_full = select_blk(
-                carry.algo_state, k_sel, avail_blk, k_t,
-                SelectCtx(t=t, complete=complete_fn), avail_full=avail_full)
+            with scope("select"):
+                mask_blk, w_blk, algo_state, completed_full = select_blk(
+                    carry.algo_state, k_sel, avail_blk, k_t,
+                    SelectCtx(t=t, complete=complete_fn),
+                    avail_full=avail_full)
             if trivial:
                 completed_blk = mask_blk
             else:
                 completed_blk = jax.lax.dynamic_slice_in_dim(
                     pad_client_dim(completed_full, n_pad), off, nl)
 
+            with scope("cohort"):
+                lb, lw, lm = cohort(k_batch, mask_blk, w_blk,
+                                    completed_full, i, off, arrays, counts)
+            params, opt_state, m = fed_round(
+                carry.params, carry.opt_state, lb, lw,
+                jnp.asarray(client_lr, jnp.float32), lm)
+
+            # masks stream packed per shard (nl % 32 == 0 ⇒ concatenated
+            # shard words == packing the full mask); the host unpacks once
+            with scope("stream"):
+                out = RoundStream(sel_mask=pack_bits(mask_blk),
+                                  completed=pack_bits(completed_blk),
+                                  k_t=k_t,
+                                  n_available=n_avail,
+                                  train_loss=m.loss,
+                                  delta_norm=m.delta_norm)
+            return EngineCarry(key, params, opt_state, algo_state,
+                               avail_state), out
+
+        def cohort(k_batch, mask_blk, w_blk, completed_full, i, off, arrays,
+                   counts):
+            """This shard's slice of the cohort: its batch rows, weights
+            and slot mask."""
             ids, valid = sharded_cohort_ids_from_mask(mask_blk, k, axis, n,
                                                       method=topk_impl)
             if k_pad > k:           # shard-count padding: zero-weight repeats
@@ -294,8 +330,9 @@ class ShardedEngine:
             # cohort weights: each slot's value lives on its owner shard
             in_range = (ids_p >= off) & (ids_p < off + nl)
             loc = jnp.where(in_range, ids_p - off, 0)
-            w_sel = jax.lax.psum(jnp.where(in_range, w_blk[loc], 0.0),
-                                 axis) * valid_p
+            w_own = jnp.where(in_range, w_blk[loc], 0.0)
+            with collective_scope(axis):
+                w_sel = jax.lax.psum(w_own, axis) * valid_p
             if not trivial:
                 # dropped slots contribute nothing even if the strategy's
                 # finalize ignored the completion hook (replicated mask,
@@ -327,26 +364,16 @@ class ShardedEngine:
                 for name, arr in arrays.items():
                     rows = arr[loc[:, None, None], idx]
                     keep = in_range.reshape((k_pad,) + (1,) * (rows.ndim - 1))
-                    batch[name] = jax.lax.psum(jnp.where(keep, rows, 0), axis)
+                    own = jnp.where(keep, rows, 0)
+                    with collective_scope(axis):
+                        batch[name] = jax.lax.psum(own, axis)
 
             # cohort-slot axis onto the mesh: each shard trains its slice
             lb = {name: jax.lax.dynamic_slice_in_dim(v, i * kb, kb)
                   for name, v in batch.items()}
             lw = jax.lax.dynamic_slice_in_dim(w_sel, i * kb, kb)
             lm = jax.lax.dynamic_slice_in_dim(slot_mask, i * kb, kb)
-            params, opt_state, m = fed_round(
-                carry.params, carry.opt_state, lb, lw,
-                jnp.asarray(client_lr, jnp.float32), lm)
-
-            # masks stream packed per shard (nl % 32 == 0 ⇒ concatenated
-            # shard words == packing the full mask); drivers unpack once
-            out = RoundStream(sel_mask=pack_bits(mask_blk),
-                              completed=pack_bits(completed_blk),
-                              k_t=k_t,
-                              n_available=n_avail,
-                              train_loss=m.loss, delta_norm=m.delta_norm)
-            return EngineCarry(key, params, opt_state, algo_state,
-                               avail_state), out
+            return lb, lw, lm
 
         def chunk_body(carry, ts, k_cap, arrays=None, counts=None):
             return jax.lax.scan(
@@ -423,7 +450,7 @@ class ShardedEngine:
             k_cap = self._k_max_dev
         else:
             k_cap = jnp.asarray(k_cap, jnp.int32)
-        if self._synth:
-            return self._chunk(carry, ts, k_cap)
-        return self._chunk(carry, ts, k_cap,
-                           self._staged.arrays, self._staged.counts)
+        staged = (() if self._synth
+                  else (self._staged.arrays, self._staged.counts))
+        with jax.profiler.TraceAnnotation("chunk_dispatch", rounds=len(ts)):
+            return self._chunk(carry, ts, k_cap, *staged)
