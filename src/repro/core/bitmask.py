@@ -6,7 +6,10 @@ masks streamed out of the compiled round loop, and the full-width mask
 movement of a round (the model is tiny; the cohort batch is (K, E, B)).
 Packing 32 clients per ``uint32`` word cuts that traffic 8× (jax bools
 are byte-sized) without touching the semantics: engines pack at the
-producer, drivers unpack once per chunk on the host.
+producer, drivers unpack once per chunk on the host.  The host decode
+(``unpack_bits_np``) is a byte-wise ``np.unpackbits`` over the words'
+little-endian bytes that writes the (…, n) bool mask once, with no
+per-bit temporaries.
 
 Layout (little-endian within a word): bit ``j`` of word ``w`` is client
 ``32*w + j``, so ``unpack(pack(m))[:n] == m`` and concatenating packed
@@ -30,7 +33,6 @@ __all__ = ["all_gather_bits", "n_words", "pack_bits", "unpack_bits",
            "unpack_bits_np"]
 
 _WORD = 32
-_SHIFTS = tuple(np.uint32(1) << np.arange(_WORD, dtype=np.uint32))
 
 
 def n_words(n: int) -> int:
@@ -64,11 +66,22 @@ def unpack_bits(words: jnp.ndarray, n: int) -> jnp.ndarray:
 
 
 def unpack_bits_np(words: np.ndarray, n: int) -> np.ndarray:
-    """Host-side :func:`unpack_bits` for driver-side chunk streams."""
-    words = np.asarray(words, np.uint32)
-    bits = (words[..., :, None] >> np.arange(_WORD, dtype=np.uint32)) & 1
-    flat = bits.reshape(words.shape[:-1] + (words.shape[-1] * _WORD,))
-    return flat[..., :n].astype(bool)
+    """Host-side :func:`unpack_bits` for driver-side chunk streams.
+
+    (…, W) words → (…, n) bool, ``n <= 32*W`` (else ``ValueError``).
+    The words are taken as C-contiguous little-endian uint32 (other
+    integer dtypes are cast), read as bytes, and decoded by
+    ``np.unpackbits``' byte-wise table straight into the (…, n) output:
+    ``count`` drops the pad bits past ``n`` and the 0/1 bytes are viewed
+    as bool, so the mask is written once.
+    """
+    words = np.ascontiguousarray(words, dtype="<u4")
+    if not 0 <= n <= _WORD * words.shape[-1]:
+        raise ValueError(f"n={n} bits do not fit in {words.shape[-1]} "
+                         f"words of {_WORD}")
+    bits = np.unpackbits(words.view(np.uint8), axis=-1, count=n,
+                         bitorder="little")
+    return bits.view(bool)
 
 
 def all_gather_bits(mask_blk: jnp.ndarray, axis: str, n: int) -> jnp.ndarray:

@@ -89,7 +89,8 @@ class RoundStream(NamedTuple):
     The two masks stream *bit-packed* — (C, ceil(N/32)) uint32 words
     (``core.bitmask``, 8× less device→host traffic per chunk than (C, N)
     bool at million-client N); the drivers unpack once per chunk
-    (``unpack_bits_np``) before any consumer sees them, so everything
+    (``unpack_bits_np``: a byte-wise ``np.unpackbits`` that writes the
+    (C, N) bool mask once) before any consumer sees them, so everything
     downstream of a driver still works on (C, N) bool.
     """
     sel_mask: jnp.ndarray      # (C, ceil(N/32)) u32 — packed cohort S_t

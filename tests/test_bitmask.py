@@ -69,6 +69,62 @@ def test_per_shard_concat_equals_full_pack():
     np.testing.assert_array_equal(per_shard, full)
 
 
+def _unpack_reference(words, n):
+    # the broadcast-shift decode: one (…, W, 32) word per bit, then slice
+    words = np.asarray(words, np.uint32)
+    bits = (words[..., :, None] >> np.arange(32, dtype=np.uint32)) & 1
+    flat = bits.reshape(words.shape[:-1] + (words.shape[-1] * 32,))
+    return flat[..., :n].astype(bool)
+
+
+def _random_words(rng, lead, n):
+    return rng.integers(0, 1 << 32, size=lead + (n_words(n),),
+                        dtype=np.uint64).astype(np.uint32)
+
+
+@pytest.mark.parametrize("lead", [(), (10,), (8, 10)])
+@pytest.mark.parametrize("n", [1, 31, 32, 33, 100, 257, 100_003])
+def test_unpack_bits_np_matches_reference(n, lead):
+    # random words, pad bits included: the byte-wise decode equals the
+    # bit-by-bit one on every leading shape the drivers stream
+    words = _random_words(np.random.default_rng(n), lead, n)
+    got = unpack_bits_np(words, n)
+    assert got.shape == lead + (n,)
+    np.testing.assert_array_equal(got, _unpack_reference(words, n))
+
+
+@pytest.mark.parametrize("make", [
+    lambda w: w.view(np.int32),                  # signed words
+    lambda w: w.astype(">u4"),                   # big-endian words
+    lambda w: np.repeat(w, 2, axis=-1)[..., ::2],  # non-contiguous slice
+], ids=["int32", "big_endian", "strided"])
+def test_unpack_bits_np_accepts_driver_inputs(make):
+    n = 1000
+    words = _random_words(np.random.default_rng(7), (10,), n)
+    np.testing.assert_array_equal(unpack_bits_np(make(words), n),
+                                  _unpack_reference(words, n))
+
+
+def test_unpack_bits_np_output_contract():
+    # every bit set, so the last word's 32*W - n pad bits are 1 on input
+    n = 70
+    words = np.full((3, n_words(n)), 0xFFFFFFFF, np.uint32)
+    got = unpack_bits_np(words, n)
+    assert got.dtype == np.bool_
+    assert got.shape == (3, n)
+    assert got.flags.c_contiguous and got.flags.writeable
+    assert got.all()                             # n real bits, no pad
+    got[0, 0] = False                            # a mask of its own
+    assert words[0, 0] == 0xFFFFFFFF
+
+
+def test_unpack_bits_np_rejects_n_past_the_words():
+    words = np.zeros((2, 3), np.uint32)
+    assert unpack_bits_np(words, 96).shape == (2, 96)
+    with pytest.raises(ValueError):
+        unpack_bits_np(words, 97)
+
+
 @pytest.mark.parametrize("n_local", [32, 24])   # packed path / bool fallback
 def test_all_gather_bits_matches_bool_gather(n_local):
     mesh = make_client_mesh(axis_name="clients")
