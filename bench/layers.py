@@ -32,22 +32,6 @@ from pathlib import Path
 ROOT = Path(__file__).resolve().parents[1]
 
 
-def chunk_text(engine, carry, size: int) -> str | None:
-    """The compiled chunk program's text, for the instruction -> op_name
-    map where the trace's op events carry no op_name."""
-    import jax
-    import numpy as np
-
-    ts = jax.device_put(np.arange(size, dtype=np.int32))
-    try:
-        return engine._chunk.lower(carry, ts,
-                                   engine._k_max_dev).compile().as_text()
-    except Exception as e:   # the trace's own stats may still name the ops
-        print(f"layers: no compiled text of the chunk: {e!r}",
-              file=sys.stderr, flush=True)
-        return None
-
-
 def drive(engine, carry, t: int, size: int, seconds: float, chunks: int):
     """Chunks back to back until ``chunks`` are done, or else ``seconds``
     have passed; returns carry, next round, rounds and seconds."""
@@ -65,15 +49,16 @@ def drive(engine, carry, t: int, size: int, seconds: float, chunks: int):
 
 
 def measure(engine, seed: int, size: int, seconds: float = 5.0,
-            chunks: int = 0, xplane: str | None = None):
-    """Both windows and the trace's events; see the module docstring."""
+            chunks: int = 0, xplane: str | None = None, model=()):
+    """Both windows and the trace's events; see the module docstring.
+    ``model``: the configuration's ``"scopes"``."""
     import jax
     from bench.lib import scopes
     from bench.lib.harness import drive_chunk, first_chunk
 
     carry, _ = first_chunk(engine, seed, size)
     carry, _, _ = drive_chunk(engine, carry, size, size)
-    text = chunk_text(engine, carry, size)
+    text = scopes.chunk_text(engine, carry, size)
     carry, t, plain_rounds, plain_s = drive(engine, carry, 2 * size, size,
                                             seconds, chunks)
     with tempfile.TemporaryDirectory() as tmp:
@@ -86,7 +71,7 @@ def measure(engine, seed: int, size: int, seconds: float = 5.0,
             for path in Path(tmp).rglob("*.xplane.pb"):
                 Path(xplane).mkdir(parents=True, exist_ok=True)
                 shutil.copy(path, Path(xplane) / path.name)
-        events = scopes.load(tmp, text)
+        events = scopes.load(tmp, text, model)
     rates = {"untraced": plain_rounds / plain_s,
              "traced": traced_rounds / traced_s}
     return events, rates
@@ -133,7 +118,8 @@ def main(argv=None) -> int:
     keep_compiled()
     engine = build_engine(cell, make_inputs(cell))
     events, rates = measure(engine, args.seed, cell.chunk_size, args.seconds,
-                            args.chunks, args.xplane)
+                            args.chunks, args.xplane,
+                            cell.config.get("scopes", ()))
     if args.events:
         Path(args.events).parent.mkdir(parents=True, exist_ok=True)
         with open(args.events, "w") as f:

@@ -25,7 +25,7 @@ import time
 import jax
 import numpy as np
 
-from . import compare, trace as trace_lib
+from . import compare, scopes, trace as trace_lib
 from .build import build_engine, make_inputs
 from .reference import run_reference
 from .spec import Cell, load_cell
@@ -46,7 +46,8 @@ class Run:
     chunk_ms: list
     sync_s: float
     peak_bytes: int | None
-    trace: dict | None = None
+    trace: dict | None = None     # trace.reduce's summary (--trace 1)
+    events: dict | None = None    # scopes.load's events (--trace 1)
 
 
 class CompileCounter:
@@ -190,9 +191,13 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
               "count": len(jax.devices()), "memory_peak_bytes": peak}
     result = {}
     if trace:
-        events = trace_lib.load(tmp.name)
+        # The compiled text maps the trace's ops to the program's scopes;
+        # fetched once the window's compiles and peak memory are read.
+        text = scopes.chunk_text(engine, carry, size)
+        run.events = scopes.load(tmp.name, text,
+                                 cell.config.get("scopes", ()))
         tmp.cleanup()
-        summary = trace_lib.reduce(events)
+        summary = trace_lib.reduce(run.events)
         run.trace = summary
         device.update(busy_s=summary["busy_s"], window_s=summary["window_s"])
         result["breakdown"] = {"device_ops": summary["device_ops"],

@@ -17,9 +17,11 @@ selection, budget, batch):
    first id (padded slots get weight 0 but train, and their loss counts);
    each slot's E minibatches of B samples drawn uniformly from the client's
    samples off the batch key;
-6. local SGD (lines 6-8): E steps of w <- w - lr grad on each client;
+6. local SGD (lines 6-8): E steps of w <- w - lr grad on each client,
+   the slots one after another;
 7. aggregation (line 9): Delta = sum_k (p_k / max(r_k, 1e-3)) (w_k - w),
-   with r after step 4; server SGD: w <- w + server_lr Delta.
+   with r after step 4, summed in slot order; server SGD:
+   w <- w + server_lr Delta.
 
 The round's loss is the mean over the k slots of each slot's mean loss
 over its E steps.  Matrix products run at ``highest`` precision, or, with
@@ -99,6 +101,40 @@ def run_reference(cell, inputs, seed: int, rounds: int, dtype=jnp.float32,
                     one_pass_dot if one_pass else jnp.dot)
 
 
+def cohort_train(cfg, loss, dtype):
+    """One round's local SGD, aggregation and server step, jitted with
+    ``params`` donated: ``train(params, batch, w)`` -> (new params, round
+    loss, |Delta|), with ``batch`` holding the k slots' E minibatches and
+    ``w`` their aggregation weights.  The slots train one after another
+    (a ``lax.scan`` in slot order), each from ``params``, and Delta is
+    summed in an accumulator of ``dtype``, so the program holds one
+    client's weights and gradient at a time whatever k is."""
+    lr = jnp.asarray(cfg["client_lr"], dtype)
+
+    def train(params, batch, w):
+        def step(wt, b):
+            value, g = jax.value_and_grad(loss)(wt, b)
+            return jax.tree.map(lambda a, d: a - lr * d, wt, g), value
+
+        def slot(acc, xs):
+            cb, wk = xs
+            w_end, values = jax.lax.scan(step, params, cb)
+            acc = jax.tree.map(
+                lambda s, a, p: s + wk.astype(a.dtype) * (a - p),
+                acc, w_end, params)
+            return acc, values.mean()
+
+        acc = jax.tree.map(jnp.zeros_like, params)
+        delta, losses = jax.lax.scan(slot, acc, (batch, w))
+        norm = jnp.sqrt(sum(jnp.sum(jnp.square(d.astype(jnp.float32)))
+                            for d in jax.tree.leaves(delta)))
+        new = jax.tree.map(lambda a, d: a + cfg["server_lr"] * d,
+                           params, delta)
+        return new, losses.astype(jnp.float32).mean(), norm
+
+    return jax.jit(train, donate_argnums=0)
+
+
 def _run(cell, inputs, seed, rounds, dtype, sdt, dot):
     cfg, mod = cell.config, cell.module
     n, k = cell.n_clients, _budget(cell.traffic)
@@ -146,25 +182,7 @@ def _run(cell, inputs, seed, rounds, dtype, sdt, dot):
                                  cnt[:, None, None])
         return {name: a[ids[:, None, None], idx] for name, a in data.items()}
 
-    @jax.jit
-    def train(params, batch, w):
-        lr = jnp.asarray(cfg["client_lr"], dtype)
-
-        def client(cb):
-            def step(wt, b):
-                value, g = jax.value_and_grad(loss)(wt, b)
-                return jax.tree.map(lambda a, d: a - lr * d, wt, g), value
-            w_end, values = jax.lax.scan(step, params, cb)
-            return jax.tree.map(jnp.subtract, w_end, params), values.mean()
-
-        deltas, losses = jax.vmap(client)(batch)
-        delta = jax.tree.map(
-            lambda d: jnp.tensordot(w.astype(d.dtype), d, axes=1), deltas)
-        norm = jnp.sqrt(sum(jnp.sum(jnp.square(d.astype(jnp.float32)))
-                            for d in jax.tree.leaves(delta)))
-        new = jax.tree.map(lambda a, d: a + cfg["server_lr"] * d,
-                           params, delta)
-        return new, losses.astype(jnp.float32).mean(), norm
+    train = cohort_train(cfg, loss, dtype)
 
     out = {name: [] for name in ("sel", "k_t", "n_available", "loss",
                                  "delta_norm")}

@@ -10,21 +10,28 @@ loop, carry copies).  A fusion carries the ``op_name`` of its root op.  The
 path comes from the op event's own stat where the profiler records one,
 else from the compiled program's text (instruction name -> ``op_name``).
 
+A configuration may list the scopes inside its model under ``"scopes"``
+in ``bench/configs/<config>.json``; those names are kept where they fall
+beneath ``local_sgd`` (``local_sgd/mamba``), so a new model brings its
+layers as data.
+
 The host marks the chunk boundary with ``TraceAnnotation`` spans whose
 keyword counters arrive as the event's stats: ``chunk_dispatch``
 (``rounds``), ``stream_decode`` (``clients``, ``bytes``), ``stream_pull``
 (``bytes``), and ``eval``, ``metrics_write``, ``checkpoint``.
 
-``load`` keeps ``trace.load``'s two keys (``devices``, ``host_spans``), so
-``trace.reduce`` reads the same dict, and adds ``scopes`` (one per device
-op) and ``spans`` (every host span with its stats).  The reductions below
-work on those lists alone.
+``load`` gives ``trace.reduce``'s two keys (``devices``, ``host_spans``)
+and adds ``scopes`` (one per device op) and ``spans`` (every host span
+with its stats).  The reductions below work on those lists alone; a
+per-layer metric's reader is one call of :func:`layer_ms_per_round`,
+:func:`span_ms_per_round` or :func:`roofline_pct`.
 """
 from __future__ import annotations
 
 import collections
 import glob
 import re
+import sys
 
 from . import trace
 
@@ -36,9 +43,11 @@ PROGRAM_SPANS = ("chunk_dispatch", "stream_decode", "stream_pull", "eval",
                  "metrics_write", "checkpoint")
 UNSCOPED = "unscoped"
 NO_SPAN = "none"
+_DEVICE = re.compile(r"/device:TPU:\d+")
+OPS_LINE = "XLA Ops"
 
-# Per-round device time of a layer: the scopes whose path starts with one
-# of these names.
+# Per-round device time of a layer (``bench/layers.py``'s table): the
+# scopes under these heads.
 LAYERS = {
     "avail_ms_per_round": ("avail", "budget"),
     "select_ms_per_round": ("select",),
@@ -55,9 +64,11 @@ _HLO_LINE = re.compile(
 _HLO_MODULE = re.compile(r"^HloModule\s+([\w.\-]+)", re.M)
 
 
-def scope_of(op_name: str | None) -> str:
+def scope_of(op_name: str | None, model=()) -> str:
     """The run of layer names in an ``op_name`` path; ``collective`` keeps
-    the mesh axis after it (``collective/clients``)."""
+    the mesh axis after it (``collective/clients``), and a name of
+    ``model`` (a configuration's ``"scopes"``) counts beneath ``local_sgd``
+    only."""
     parts = (op_name or "").split("/")
     out, i = [], 0
     while i < len(parts):
@@ -65,10 +76,26 @@ def scope_of(op_name: str | None) -> str:
             out.append(f"collective/{parts[i + 1]}")
             i += 2
             continue
-        if parts[i] in SCOPES:
+        if parts[i] in SCOPES or (parts[i] in model and "local_sgd" in out):
             out.append(parts[i])
         i += 1
     return "/".join(out) or UNSCOPED
+
+
+def chunk_text(engine, carry, size: int) -> str | None:
+    """The compiled chunk program's text, for the instruction -> op_name
+    map where the trace's op events carry no op_name."""
+    import jax
+    import numpy as np
+
+    ts = jax.device_put(np.arange(size, dtype=np.int32))
+    try:
+        return engine._chunk.lower(carry, ts,
+                                   engine._k_max_dev).compile().as_text()
+    except Exception as e:   # the trace's own stats may still name the ops
+        print(f"scopes: no compiled text of the chunk: {e!r}",
+              file=sys.stderr, flush=True)
+        return None
 
 
 def hlo_op_names(hlo_text: str) -> tuple[str | None, dict]:
@@ -94,11 +121,12 @@ def _op_name(name: str, stats: dict, module: str | None,
     return by_instr.get(str(instr))
 
 
-def load(trace_dir: str, hlo_text: str | None = None) -> dict:
-    """``trace.load``'s device ops and harness spans, plus each op's scope
-    and every host span (harness and program) with its stats.  On the CPU,
-    which has no device plane, the ops are the host events that name an
-    ``hlo_op``."""
+def load(trace_dir: str, hlo_text: str | None = None, model=()) -> dict:
+    """Each device's ops (the ``XLA Ops`` line of every TPU plane) with
+    their scopes, the harness's host spans, and every host span (harness
+    and program) with its stats.  ``model``: the configuration's
+    ``"scopes"``.  On the CPU, which has no device plane, the ops are the
+    host events that name an ``hlo_op``."""
     from jax.profiler import ProfileData
 
     paths = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
@@ -106,18 +134,25 @@ def load(trace_dir: str, hlo_text: str | None = None) -> dict:
         raise RuntimeError(f"expected one xplane.pb under {trace_dir}, "
                            f"found {paths}")
     module, by_instr = hlo_op_names(hlo_text) if hlo_text else (None, {})
+    model = frozenset(model)
     names = set(trace.SPANS) | set(PROGRAM_SPANS)
     devices, scopes, spans, cpu_ops = {}, {}, [], []
     for plane in ProfileData.from_file(paths[0]).planes:
-        if trace._DEVICE.fullmatch(plane.name):
-            ops, sc = [], []
+        if _DEVICE.fullmatch(plane.name):
+            # An op event's name is its instruction's whole text, so the
+            # stats, slow to read, are read once per instruction.
+            ops, sc, by_name = [], [], {}
             for line in plane.lines:
-                if line.name != trace.OPS_LINE:
+                if line.name != OPS_LINE:
                     continue
                 for ev in line.events:
-                    ops.append((ev.name, ev.start_ns, ev.end_ns))
-                    sc.append(scope_of(_op_name(ev.name, _stats(ev), module,
-                                                by_instr)))
+                    name = ev.name
+                    ops.append((name, ev.start_ns, ev.end_ns))
+                    if name not in by_name:
+                        by_name[name] = scope_of(
+                            _op_name(name, _stats(ev), module, by_instr),
+                            model)
+                    sc.append(by_name[name])
             devices[plane.name], scopes[plane.name] = ops, sc
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
@@ -130,7 +165,8 @@ def load(trace_dir: str, hlo_text: str | None = None) -> dict:
                     if "hlo_op" in stats:
                         cpu_ops.append((ev.name, ev.start_ns, ev.end_ns,
                                         scope_of(_op_name(ev.name, stats,
-                                                          module, by_instr))))
+                                                          module, by_instr),
+                                                 model)))
     if not devices and cpu_ops:
         devices["/host:CPU"] = [op[:3] for op in cpu_ops]
         scopes["/host:CPU"] = [op[3] for op in cpu_ops]
@@ -219,36 +255,82 @@ def rounds_in(spans, lo: float, hi: float) -> int:
                    if n == "chunk_dispatch" and lo <= s < hi))
 
 
-def summarize(events: dict) -> dict:
-    """The window's rounds, busy time, self time by scope and idle time by
-    span (ns, mean over devices), and the per-round layer times (ms).  A
-    layer whose scope the trace lacks (a program without scopes) reads
-    None, as does everything per round when no ``chunk_dispatch`` span
-    counted rounds."""
-    lo, hi = window(events)
+def _devices(events: dict) -> dict:
     devices = events["devices"]
     if not devices or not any(devices.values()):
         raise RuntimeError("the trace holds no device operation")
+    return devices
+
+
+def self_ns(events: dict) -> dict:
+    """Self time by scope within the window (ns, mean over devices),
+    largest first; worked out once and kept in ``events``."""
+    if "_self_ns" not in events:
+        lo, hi = window(events)
+        devices = _devices(events)
+        total = collections.Counter()
+        for dev, ops in devices.items():
+            total.update(self_time(ops, events["scopes"][dev], lo, hi))
+        events["_self_ns"] = {k: v / len(devices)
+                              for k, v in total.most_common()}
+    return events["_self_ns"]
+
+
+def layer_ms_per_round(events: dict, heads) -> float | None:
+    """Device self time of the scopes under ``heads`` (a head and every
+    scope beneath it: ``select`` takes ``select/topk``) per round of the
+    window, in ms.  None where the trace has no such scope (a program
+    without scopes) or no ``chunk_dispatch`` span counted rounds."""
+    t = [v for k, v in self_ns(events).items()
+         if any(k == h or k.startswith(h + "/") for h in heads)]
+    rounds = rounds_in(events["spans"], *window(events))
+    return 1e-6 * sum(t) / rounds if t and rounds else None
+
+
+def span_ms_per_round(events: dict, name: str) -> float | None:
+    """Host time in the spans called ``name`` per round of the window, in
+    ms; None where the trace has no such span or counted no rounds."""
+    lo, hi = window(events)
+    rounds = rounds_in(events["spans"], lo, hi)
+    if not rounds or not any(sp[0] == name for sp in events["spans"]):
+        return None
+    return 1e-6 * span_time(events["spans"], name, lo, hi) / rounds
+
+
+def roofline_pct(events: dict, heads, flops: float, nbytes: float,
+                 peak: dict) -> float | None:
+    """A layer's share of the chip's roofline: the least time its work
+    could take, the larger of ``flops`` over peak FLOP/s and ``nbytes``
+    over peak HBM bytes/s (one round's, counted from shapes; ``peak`` from
+    ``peaks.peak_of``), over its measured time per round
+    (:func:`layer_ms_per_round`).  None where that is None."""
+    least = max(flops / peak["flops_per_s"], nbytes / peak["hbm_bytes_per_s"])
+    if least <= 0:
+        raise ValueError("a roofline needs the layer's FLOPs or bytes")
+    ms = layer_ms_per_round(events, heads)
+    return None if not ms else 100.0 * least / (1e-3 * ms)
+
+
+def summarize(events: dict) -> dict:
+    """The window's rounds, busy time, self time by scope and idle time by
+    span (ns, mean over devices), and the per-round layer times (ms) of
+    ``LAYERS`` and the ``stream_decode`` spans, None where the trace lacks
+    them (:func:`layer_ms_per_round`)."""
+    lo, hi = window(events)
+    devices = _devices(events)
     n = len(devices)
-    by_scope, idle, busy = collections.Counter(), collections.Counter(), 0.0
-    for dev, ops in devices.items():
-        by_scope.update(self_time(ops, events["scopes"][dev], lo, hi))
+    idle, busy = collections.Counter(), 0.0
+    for ops in devices.values():
         idle.update(idle_by_span(ops, events["spans"], lo, hi))
         busy += sum(e - s for s, e in trace.merge(
             [(s, e) for _, s, e in ops], lo, hi))
-    by_scope = {k: v / n for k, v in by_scope.most_common()}
     idle = {k: v / n for k, v in idle.most_common()}
-    rounds = rounds_in(events["spans"], lo, hi)
-    layers = {}
-    for metric, heads in LAYERS.items():
-        t = [v for k, v in by_scope.items() if k.split("/")[0] in heads]
-        layers[metric] = (1e-6 * sum(t) / rounds if t and rounds else None)
-    decode = any(sp[0] == "stream_decode" for sp in events["spans"])
-    layers["decode_ms_per_round"] = (
-        1e-6 * span_time(events["spans"], "stream_decode", lo, hi) / rounds
-        if decode and rounds else None)
-    return {"window_ns": hi - lo, "rounds": rounds, "busy_ns": busy / n,
-            "self_ns": by_scope, "idle_ns": idle, "layers": layers}
+    layers = {metric: layer_ms_per_round(events, heads)
+              for metric, heads in LAYERS.items()}
+    layers["decode_ms_per_round"] = span_ms_per_round(events, "stream_decode")
+    return {"window_ns": hi - lo, "rounds": rounds_in(events["spans"], lo, hi),
+            "busy_ns": busy / n, "self_ns": self_ns(events), "idle_ns": idle,
+            "layers": layers}
 
 
 def table(title: str, ns: dict, rounds: int, total: float) -> str:

@@ -3,7 +3,9 @@
 A cell is one entry of ``workloads`` in ``BENCHMARK.json``.  Everything
 that belongs to it sits in files of its own:
 
-* ``bench/configs/<config>.json`` — the configuration as it is run;
+* ``bench/configs/<config>.json`` — the configuration as it is run, with
+  the named scopes inside its model under ``"scopes"`` where it has any
+  (``scopes.scope_of``);
 * ``bench/configs/<config>.py``   — its model pieces, data maker, plain
   reference and per-round operation counts;
 * ``bench/traffic/<traffic>.json`` — the traffic mix (population,

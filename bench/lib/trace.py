@@ -1,44 +1,17 @@
 """From the profiler's trace of the window to device busy time, idle share,
 top device operations and idle gaps by what the host was doing.
 
-``load`` reads an ``.xplane.pb`` into plain lists: each device's operation
-intervals (the ``XLA Ops`` line of every ``/device:TPU:<i>`` plane) and the
-harness's own host spans (``window``, ``dispatch``, ``wait``, ``sync``).
-``reduce`` works on those lists alone, so it is tested on a small
-recorded trace.
+``reduce`` works on plain lists, as ``scopes.load`` reads them from the
+``.xplane.pb``: each device's operation intervals (``devices``) and the
+harness's own host spans (``host_spans``: ``window``, ``dispatch``,
+``wait``, ``sync``), so it is tested on hand-made and recorded events.
 """
 from __future__ import annotations
 
 import bisect
 import collections
-import glob
-import re
 
 SPANS = ("window", "dispatch", "wait", "sync")
-_DEVICE = re.compile(r"/device:TPU:\d+")
-OPS_LINE = "XLA Ops"
-
-
-def load(trace_dir: str) -> dict:
-    from jax.profiler import ProfileData
-
-    paths = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
-    if len(paths) != 1:
-        raise RuntimeError(f"expected one xplane.pb under {trace_dir}, "
-                           f"found {paths}")
-    devices, spans, lines = {}, [], {}
-    for plane in ProfileData.from_file(paths[0]).planes:
-        lines[plane.name] = [line.name for line in plane.lines]
-        if _DEVICE.fullmatch(plane.name):
-            devices[plane.name] = [
-                (ev.name, ev.start_ns, ev.end_ns)
-                for line in plane.lines if line.name == OPS_LINE
-                for ev in line.events]
-        elif plane.name.startswith("/host:"):
-            spans += [(ev.name, ev.start_ns, ev.end_ns)
-                      for line in plane.lines for ev in line.events
-                      if ev.name in SPANS]
-    return {"devices": devices, "host_spans": spans, "lines": lines}
 
 
 def merge(intervals, lo: float, hi: float) -> list:

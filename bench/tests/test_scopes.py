@@ -1,10 +1,13 @@
 """The per-layer reduction (``bench/lib/scopes.py``): self time by scope
 and idle time by innermost host span on hand-made events, scope
 attribution through a real CPU profiler trace of a small chunk, and a
-trace recorded on the chip."""
+trace recorded on the chip; the per-layer metrics' readers on it."""
+import copy
+import importlib
 import itertools
 import json
 from pathlib import Path
+from types import SimpleNamespace
 
 import pytest
 
@@ -22,6 +25,62 @@ def test_scope_of_keeps_the_layer_names_and_the_mesh_axis():
         "aggregate/collective/clients"
     assert scopes.scope_of("jit(chunk)/while/body/add") == scopes.UNSCOPED
     assert scopes.scope_of(None) == scopes.UNSCOPED
+
+
+def test_scope_of_keeps_a_configurations_scopes_beneath_local_sgd():
+    path = ("jit(chunk)/while/body/closed_call/local_sgd/while/body/"
+            "mamba/ssd/dot_general")
+    assert scopes.scope_of(path, ("mamba", "ssd")) == "local_sgd/mamba/ssd"
+    assert scopes.scope_of(path, ("mamba",)) == "local_sgd/mamba"
+    # a name no configuration lists folds into its round layer
+    assert scopes.scope_of(path) == "local_sgd"
+    assert scopes.scope_of(path, ("attention",)) == "local_sgd"
+    # a listed name outside local_sgd is no layer
+    assert scopes.scope_of("jit(chunk)/while/body/mamba/add",
+                           ("mamba",)) == scopes.UNSCOPED
+
+
+def test_a_configurations_scope_becomes_a_layer_time_and_a_roofline(
+        tmp_path):
+    """A CPU trace of a program with scopes inside ``local_sgd``: the one
+    that ``model`` lists is read as a layer of its own, by the helpers a
+    reader calls, and one it does not list falls to ``local_sgd``."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def step(x):
+        with jax.named_scope("local_sgd"):
+            with jax.named_scope("mamba"):
+                x = jnp.sin(x) @ x
+            with jax.named_scope("attention"):
+                x = jnp.tanh(x) @ x
+        return x
+
+    x = jnp.ones((256, 256))
+    step(x).block_until_ready()
+    jax.profiler.start_trace(str(tmp_path))
+    with jax.profiler.TraceAnnotation("window"):
+        for _ in range(3):
+            with jax.profiler.TraceAnnotation("chunk_dispatch", rounds=10):
+                y = step(x)
+            y.block_until_ready()
+    jax.profiler.stop_trace()
+    text = step.lower(x).compile().as_text()
+    events = scopes.load(str(tmp_path), text, ("mamba",))
+    found = set(scopes.self_ns(events))
+    assert {"local_sgd/mamba", "local_sgd"} <= found, found
+    ms = scopes.layer_ms_per_round(events, ("local_sgd/mamba",))
+    assert ms is not None and ms > 0
+    assert scopes.layer_ms_per_round(events, ("local_sgd",)) > ms
+    peak = {"flops_per_s": 1e12, "hbm_bytes_per_s": 1e11}
+    flops = 2 * 256**3 * 3 / 30          # one round's share of the dots
+    pct = scopes.roofline_pct(events, ("local_sgd/mamba",), flops, 0.0,
+                              peak)
+    assert pct == pytest.approx(100 * flops / 1e12 / (1e-3 * ms))
+    assert scopes.layer_ms_per_round(events, ("local_sgd/ssd",)) is None
+    assert scopes.roofline_pct(events, ("local_sgd/ssd",), flops, 0.0,
+                               peak) is None
 
 
 def test_hlo_op_names_maps_instructions_to_op_name():
@@ -176,3 +235,63 @@ def test_recorded_chip_trace_is_one_clock_up_to_an_offset(recorded):
     lo = max(d[0] - c[0][0] for d, c in zip(dispatch, chunks))
     hi = min(w - max(e for _, e in c) for w, c in zip(waits, chunks))
     assert slack < lo <= hi, (lo, hi)
+
+
+# Each per-layer metric's reader on the recorded chip trace, in ms per
+# round: the values ``scopes.summarize`` gave for it when recorded.
+RECORDED_LAYERS = {
+    "decode_ms_per_round": 0.00954405,
+    "avail_ms_per_round": 0.00227085,
+    "select_ms_per_round": 0.00652835,
+    "cohort_ms_per_round": 0.03093005,
+    "local_sgd_ms_per_round": 0.01963535,
+    "aggregate_ms_per_round": 0.00123025,
+}
+
+
+def reader(name):
+    return importlib.import_module(f"bench.metrics.{name}").read
+
+
+@pytest.mark.parametrize("name", sorted(RECORDED_LAYERS))
+def test_reader_reads_the_recorded_trace(recorded, name):
+    events = copy.deepcopy(recorded)
+    value = reader(name)(SimpleNamespace(events=events))
+    assert value == pytest.approx(RECORDED_LAYERS[name], rel=1e-9)
+    assert scopes.summarize(events)["layers"][name] == value
+
+
+@pytest.mark.parametrize("name", sorted(RECORDED_LAYERS))
+def test_reader_finds_nothing_without_scopes(name):
+    events = {"devices": {"/device:TPU:0": [("while", 10, 90),
+                                            ("fusion", 20, 40)]},
+              "scopes": {"/device:TPU:0": [scopes.UNSCOPED] * 2},
+              "spans": [("window", 0, 100, {}),
+                        ("chunk_dispatch", 5, 8, {"rounds": 10}),
+                        ("sync", 90, 99, {})]}
+    assert reader(name)(SimpleNamespace(events=events)) is None
+    assert reader(name)(SimpleNamespace(events=None)) is None
+
+
+def test_traced_run_reports_the_layer_metrics():
+    """A ``--trace 1`` run on the CPU, the look for a chip skipped: the
+    harness fetches the chunk's compiled text after the window and hands
+    the scoped events to the readers.  ``step_mfu_pct`` is left out, as
+    the CPU has no published peak."""
+    import dataclasses
+    import time
+
+    from bench.lib.harness import run_cell
+    from bench.lib.spec import load_cell
+
+    cell = load_cell("synthetic_softmax.paper")
+    cell = dataclasses.replace(cell, per_layer=tuple(
+        m for m in cell.per_layer if m["name"] != "step_mfu_pct"))
+    result = run_cell(cell, 2**31 + 23, 0.5, True,
+                      t_start=time.perf_counter())
+    assert result["correct"], result["checks"]
+    got = result["metrics"]
+    assert set(got) == {m["name"] for m in cell.per_layer}
+    for name in RECORDED_LAYERS:
+        assert got[name]["value"] > 0 and got[name]["unit"] == "ms", name
+    assert result["device"]["busy_s"] > 0
